@@ -24,8 +24,14 @@ from typing import Callable
 
 import jax
 
+from repro.launch.compile_cache import enable_compile_cache
+
 BENCH_SCHEMA_VERSION = 1
 REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
+
+# Only the BENCH writers import this module, before their first
+# compile: turn the persistent compilation cache on for all of them.
+enable_compile_cache()
 
 
 def bench_path(name: str) -> pathlib.Path:

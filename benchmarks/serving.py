@@ -164,7 +164,6 @@ def measure_bucket_throughput(
         reps = 6 if b == 1 else 3 if b <= 8 else 2 if b <= 32 else 1
 
         def call(b=b):
-            # fresh operand per call: serve_fn donates on accelerators
             x = jax.random.normal(jax.random.fold_in(key, b),
                                   (b, 32, 32, 3))
             return fn(fused_params, x)

@@ -13,7 +13,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.distributed import compression
@@ -36,7 +35,7 @@ def main():
 
     @jax.jit
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(), P("data"), P("data"), P()),
         out_specs=(P(), P()),
     )

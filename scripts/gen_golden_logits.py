@@ -43,10 +43,15 @@ OUT = ROOT / "tests" / "golden" / "bnn_logits.json"
 DEFAULT_CKPT = ROOT / "tests" / "golden" / "bnn_trained_ckpt.npz"
 
 
+def golden_images(seed: int = IMAGE_SEED, batch: int = BATCH) -> np.ndarray:
+    """The fixture's input images: standard normal float32 drawn with
+    NumPy's PCG64 generator, so they depend on no jax PRNG default."""
+    rng = np.random.default_rng(seed)
+    return rng.standard_normal((batch, 32, 32, 3)).astype(np.float32)
+
+
 def compute_logits(params) -> np.ndarray:
-    images = jax.random.normal(
-        jax.random.PRNGKey(IMAGE_SEED), (BATCH, 32, 32, 3)
-    )
+    images = golden_images()
     logits = bnn_apply(
         pack_bnn_params(params), images,
         BNNConfig(mode=QuantMode.PACKED, engine="xla"),
@@ -83,8 +88,8 @@ def main():
     fixture = {
         "description": (
             "PACKED (engine=xla) logits of the CIFAR BNN for "
-            f"{src_desc} on "
-            f"normal(PRNGKey({IMAGE_SEED}), ({BATCH}, 32, 32, 3)). "
+            f"{src_desc} on np.random.default_rng({IMAGE_SEED})"
+            f".standard_normal(({BATCH}, 32, 32, 3)) as float32. "
             "float32 hex — exact. Regenerate ONLY for intentional "
             "numeric changes: scripts/gen_golden_logits.py"
         ),

@@ -28,7 +28,6 @@ and maxpool becomes a bitwise OR on words (DESIGN.md §4).
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Optional
 
 import jax
@@ -453,11 +452,10 @@ def bnn_serve_fn(
     compiles once per input shape — exactly the contract the serving
     executor cache (``repro.serve.executor``) builds on: one executable
     per ``(bucket, engine, conv_impl, blocks)`` key. The ``images``
-    buffer is donated: a serving batch is consumed by its dispatch, so
-    on accelerators XLA may reuse its pages for intermediates instead
-    of holding both alive. (The CPU backend cannot use donations and
-    warns on every compile, so the annotation is applied only where it
-    can take effect.)
+    buffer is not donated: XLA can reuse a donated input only for an
+    output of its shape, and the ``[N, 10]`` logits never match the
+    ``[N, 32, 32, 3]`` images (a TPU compile reports every such
+    donation unusable).
 
     ``ragged=True`` (the continuous scheduler's executors) routes the
     megakernel FC trunk through the masked-tail batch path so variable
@@ -481,8 +479,6 @@ def bnn_serve_fn(
     if engine not in SERVE_ENGINES:
         raise ValueError(f"unknown serving engine {engine!r}; "
                          f"expected one of {SERVE_ENGINES}")
-    donate = (1,) if jax.default_backend() != "cpu" else ()
-
     if engine in ("megakernel", "megakernel_xla"):
         inner = "xnor" if engine == "megakernel" else "xla"
 
@@ -499,22 +495,20 @@ def bnn_serve_fn(
             )
 
     if mesh is not None:
-        from jax.experimental.shard_map import shard_map
-
         from repro.distributed.sharding import serve_specs
 
         p_spec, x_spec, y_spec = serve_specs(mesh)
-        # check_rep=False: the Pallas kernel calls inside the per-shard
+        # check_vma=False: the Pallas kernel calls inside the per-shard
         # program carry no replication rules; correctness rests on the
         # per-sample independence of the forward, asserted bit-exactly
         # in the sharded test matrix.
-        apply_fn = shard_map(
+        apply_fn = jax.shard_map(
             apply_fn, mesh=mesh,
             in_specs=(p_spec, x_spec), out_specs=y_spec,
-            check_rep=False,
+            check_vma=False,
         )
 
-    return functools.partial(jax.jit, donate_argnums=donate)(apply_fn)
+    return jax.jit(apply_fn)
 
 
 def bnn_loss(params, images, labels, cfg: BNNConfig):

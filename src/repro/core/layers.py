@@ -31,6 +31,10 @@ from repro.kernels import ops as kops
 from repro.kernels.autotune import AUTO, block_kwargs
 
 
+# Matmul precision for real-valued operands: float32 on every backend.
+_F32_DOT = lax.Precision.HIGHEST
+
+
 @dataclasses.dataclass(frozen=True)
 class BitLinearConfig:
     mode: QuantMode = QuantMode.FAKE_QUANT
@@ -580,11 +584,17 @@ def bit_conv2d(
                 wm, scale_axis=-1 if cfg.use_scale else None
             )
             xq = binarize_activations(x2d) if cfg.binarize_acts else x2d
-            y2d = xq @ wq.astype(x2d.dtype).T
+            # ±1 x ±1 products are exact at any matmul precision; a
+            # real-valued input (the first conv) is not, and a TPU runs
+            # a float32 dot at bfloat16 precision unless told otherwise.
+            y2d = jnp.matmul(
+                xq, wq.astype(x2d.dtype).T,
+                precision=None if cfg.binarize_acts else _F32_DOT,
+            )
             if alpha is not None:
                 y2d = y2d * alpha.reshape(1, -1).astype(y2d.dtype)
         else:
-            y2d = x2d @ wm.astype(x2d.dtype).T
+            y2d = jnp.matmul(x2d, wm.astype(x2d.dtype).T, precision=_F32_DOT)
 
     if "b" in params:
         y2d = y2d + params["b"].astype(y2d.dtype)

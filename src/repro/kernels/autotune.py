@@ -9,10 +9,11 @@ choice, in three layers:
    compute the per-grid-step VMEM bytes of each kernel from its block
    shape (both the legacy ``broadcast`` and the ``loop`` formulation,
    so benchmarks can report the reduction).
-2. **Heuristic defaults** — :func:`heuristic_gemm_blocks` /
-   :func:`heuristic_conv_block_d` pick the largest aligned tiles whose
+2. **Heuristic defaults** — :func:`heuristic_gemm_blocks` picks the
+   largest TPU-legal tiles (:func:`gemm_blocks_legal`) whose
    double-buffered footprint fits a conservative VMEM budget, clamped
-   to the (padded) problem shape. This is what ``block_*="auto"``
+   to the (padded) problem shape; :func:`heuristic_conv_block_d` takes
+   the whole output-channel extent. This is what ``block_*="auto"``
    resolves to when no tuned entry exists.
 3. **Measured tuning** — :func:`tune` times a kernel wrapper across a
    candidate grid and persists the winner in a JSON cache keyed by
@@ -29,7 +30,7 @@ the shape dims in sorted-name order)::
     {"version": 1,
      "entries": {
        "fused_xnor_gemm|kw=128|m=512|n=512": {
-         "jax": "0.4.37", "device": "cpu",
+         "jax": "0.9.0", "device": "TPU v5 lite",
          "block_m": 256, "block_n": 256, "block_kw": 32,
          "word_group": 8, "wall_s": 0.0123}}}
 
@@ -156,48 +157,70 @@ def _round_up(x: int, mult: int) -> int:
     return -(-x // mult) * mult
 
 
+def _m_step(fused: bool) -> int:
+    """Smallest legal partial M tile. The kernels read word-major
+    weights ``wt [bkw, bm]`` (bm on lanes: a multiple of 128), and the
+    fused kernel writes packed ``[bm/32, bn]`` tiles (bm/32 on
+    sublanes: a multiple of 8, so bm a multiple of 256)."""
+    return 256 if fused else 128
+
+
+def gemm_blocks_legal(m: int, kw: int, n: int, cfg: BlockConfig, *,
+                      fused: bool = False) -> bool:
+    """Whether ``cfg`` tiles an ``[M, KW] x [KW, N]`` (fused_)xnor_gemm
+    or unpack_gemm the way the TPU lowering requires: each block's last
+    two dims a multiple of (8, 128) or the whole padded array dim. The
+    operand blocks are ``wt [bkw, bm]``, ``x [bkw, bn]`` and the output
+    ``[bm, bn]`` (fused: ``[bm/32, bn]``); a block at least as large as
+    its padded dim is clamped to it by :func:`resolve_gemm_blocks`."""
+    m_full = _round_up(max(m, 1), PACK_BITS if fused else 8)
+    return (
+        (cfg.block_m >= m_full or cfg.block_m % _m_step(fused) == 0)
+        and (cfg.block_kw >= kw or cfg.block_kw % 8 == 0)
+        and cfg.block_n % 128 == 0
+    )
+
+
 def heuristic_gemm_blocks(
     m: int, kw: int, n: int, *, fused: bool = False, unpack: bool = False,
     vmem_budget: int = VMEM_BUDGET_BYTES,
 ) -> BlockConfig:
-    """Largest aligned tiles fitting ``vmem_budget``, clamped to shape.
+    """Largest TPU-legal tiles fitting ``vmem_budget``, clamped to shape.
 
     Starts from the loop-formulation ceiling (bm=bn=512, bkw=64 — ~9x
     the old broadcast default's work per step at ~2.6 MiB) and halves
-    the largest contributor until the model fits. Floors: bm >= 32
-    (whole packed output words when fused), bn >= 128 (one lane tile),
-    bkw >= 1. With ``unpack=True`` the model charges the in-VMEM
-    unpacked ±1 weight tile, so ``bkw`` lands much smaller (each packed
-    K-word is 32 real rows of the MXU contraction).
+    the largest contributor until the model fits, staying on the tile
+    grid :func:`gemm_blocks_legal` checks: ``bm`` is the whole padded M
+    or a multiple of 128 (256 when fused), ``bn`` a multiple of 128,
+    ``bkw`` all KW words or a multiple of 8. With ``unpack=True`` the
+    model charges the in-VMEM unpacked ±1 weight tile, so ``bkw`` lands
+    much smaller (each packed K-word is 32 real rows of the MXU
+    contraction).
     """
-    m_mult = PACK_BITS if fused else 8
-    bm = min(512, _round_up(max(m, 1), m_mult))
+    step = _m_step(fused)
+    bm = min(512, _round_up(max(m, 1), PACK_BITS if fused else 8))
     bn = min(512, _round_up(max(n, 1), 128))
     bkw = min(64, max(kw, 1))
     while gemm_step_vmem(bm, bn, bkw, fused=fused, unpack=unpack) > vmem_budget:
-        if bm >= bn and bm > m_mult:
-            bm = max(m_mult, bm // 2)
+        if bm >= bn and bm > step:
+            bm = max(step, bm // 2 // step * step)
         elif bn > 128:
             bn = max(128, bn // 2)
-        elif bkw > 1:
-            bkw = max(1, bkw // 2)
+        elif bkw > 8:
+            bkw = max(8, bkw // 2 // 8 * 8)
         else:
             break  # floors reached; nothing left to shrink
     return BlockConfig(block_m=bm, block_n=bn, block_kw=bkw)
 
 
-def heuristic_conv_block_d(
-    d: int, hp: int, wp: int, cw: int, kh: int, kw: int, ow: int,
-    *, fused: bool = True, vmem_budget: int = VMEM_BUDGET_BYTES,
-) -> BlockConfig:
-    """Output-channel tile for the direct-conv kernels."""
-    bd = min(256, _round_up(max(d, 1), PACK_BITS))
-    while (
-        conv_step_vmem(hp, wp, cw, bd, kh, kw, ow, fused=fused) > vmem_budget
-        and bd > PACK_BITS
-    ):
-        bd = max(PACK_BITS, bd // 2)
-    return BlockConfig(block_m=bd)
+def heuristic_conv_block_d(d: int) -> BlockConfig:
+    """Output-channel tile for the direct-conv kernels: all of D. The
+    output tile ``[1, 1, OW, block_d/32]`` (fused) / ``[..., block_d]``
+    puts the channel words on lanes, where a partial tile would have to
+    be a multiple of 128 words (4096 channels); a CIFAR-scale layer's
+    whole filter bank is a few hundred KiB of VMEM
+    (:func:`conv_step_vmem`)."""
+    return BlockConfig(block_m=_round_up(max(d, 1), PACK_BITS))
 
 
 # ---------------------------------------------------------------------------
@@ -357,7 +380,7 @@ def default_gemm_candidates(
             block_kw=min(bkw, max(kw, 1)),
             word_group=grp,
         )
-        if cfg not in seen:
+        if cfg not in seen and gemm_blocks_legal(m, kw, n, cfg, fused=fused):
             seen.add(cfg)
             out.append(cfg)
     return out
@@ -606,7 +629,7 @@ def resolve_gemm_blocks(
 
 def resolve_conv_block_d(
     kernel: str, d: int, hp: int, wp: int, cw: int, kh: int, kw: int,
-    ow: int, block_d, word_group, *, fused: bool = True,
+    ow: int, block_d, word_group,
 ) -> tuple[int, int]:
     """Conv sibling of :func:`resolve_gemm_blocks` (block_d only).
 
@@ -625,9 +648,7 @@ def resolve_conv_block_d(
                  "ow": ow},
             )
         if cfg is None:
-            cfg = heuristic_conv_block_d(
-                d, hp, wp, cw, kh, kw, ow, fused=fused
-            )
+            cfg = heuristic_conv_block_d(d)
         block_d = cfg.block_m if _is_auto(block_d) else block_d
         word_group = cfg.word_group if _is_auto(word_group) else word_group
     block_d = max(
@@ -656,6 +677,7 @@ __all__ = [
     "gemm_step_vmem",
     "conv_step_vmem",
     "megakernel_vmem",
+    "gemm_blocks_legal",
     "heuristic_gemm_blocks",
     "heuristic_conv_block_d",
     "heuristic_megakernel_block_n",

@@ -7,8 +7,9 @@ than the packed activation map it was gathered from. This kernel
 convolves the channel-packed map directly: the grid tiles the output
 pixel space ``(N, OH)`` x output channels ``D``, each program holds the
 whole (pre-padded) packed image ``[Hp, Wp, CW]`` in VMEM, gathers its
-kH*kW window rows with strided in-VMEM slices, runs the xnor-popcount
-accumulation against the tap-aligned packed filter tile, and finishes
+kH*kW taps with strided in-VMEM loads into word rows ``[kH*kW*CW, OW]``
+(VMEM scratch), runs the xnor-popcount accumulation against the
+tap-aligned packed filter tile (word-major, see popcount.py), and finishes
 with the PR-1 fused epilogue (folded-BN affine -> sign -> repack along
 D). HBM sees: the packed map (read), the packed filters (read), the
 packed output (write). The patch matrix never exists.
@@ -25,16 +26,15 @@ The popcount accumulation is BROADCAST-FREE (DESIGN.md §6): a
 intermediate never exists. ``accum="broadcast"`` keeps the legacy
 formulation for A/B benchmarking only.
 
-VMEM budget per grid step (CIFAR BNN worst case, block_d=128):
-  x map     1*34*34*16*4  =  72 KiB   (conv5: Hp=Wp=10 -> 6 KiB)
-  w tile    128*144*4     =  72 KiB   (KW = 9*16 words max)
-  a, b      128*1*4 x2    =   1 KiB
-  xnor      128*32*4      =  16 KiB   (one 2-D word term; was 2304 KiB)
-  out       32*4*4        = 0.5 KiB
-~162 KiB of ~16 MiB VMEM (was ~2.4 MiB). The map block is revisited across the OH and
-D grid axes (same block index), so the pipeline fetches it once per
-image. When the packed map itself outgrows VMEM (or kH*kW is large and
-C tiny, so the patch blow-up the kernel avoids is small), fall back to
+The D tile is the whole padded D: the output tile puts the channel
+words on lanes, where the TPU accepts a partial tile only in multiples
+of 128 words. The TPU compiler counts ~1.8 MiB of scoped VMEM for
+conv1 (the ``[34, 34, 4]`` map block pads to 128 lanes) and ~2.2 MiB
+for conv5 (``tests/test_tpu_compile.py`` compiles every CIFAR layer).
+The map block is revisited across the OH and D grid axes (same block
+index), so the pipeline fetches it once per image. When the packed map
+itself outgrows VMEM (or kH*kW is large and C tiny, so the patch
+blow-up the kernel avoids is small), fall back to
 ``conv_impl="im2col"`` — the GEMM tiles arbitrarily large operands.
 """
 
@@ -46,79 +46,116 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bitops import PACK_BITS
-from repro.kernels import pallas_compat
 from repro.kernels.popcount import (
     DEFAULT_WORD_GROUP,
-    accum_popcount_rows,
+    accum_popcount_km,
     sign_repack_m,
 )
 
 
-def _gather_windows(x_ref, oh_idx, *, kh: int, kw: int, stride: int, ow: int):
+def _gather_windows(x_ref, xs_ref, oh_idx, *, kh: int, kw: int,
+                    stride: int, ow: int):
     """Gather one output row's windows from the padded map in VMEM.
 
-    x_ref: [1, Hp, Wp, CW]. Returns [OW, kH*kW*CW] int32 — tap-major
-    word order (i*kW + j)*CW + cw, matching pack_conv_aligned rows.
+    x_ref: [1, Hp, Wp, CW]. Fills ``xs_ref [kH*kW*CW, OW]`` with word
+    rows — row ``(i*kW + j)*CW + cw`` holds tap (i, j), word cw of the
+    row's OW windows: the ``[KW, N]`` operand layout of
+    ``accum_popcount_km``, in the tap-major pack_conv_aligned order.
+    Each tap is one stride-``stride`` sublane read of the map row.
     """
     cw = x_ref.shape[-1]
-    taps = []
     for i in range(kh):
-        row = x_ref[0, pl.ds(oh_idx * stride + i, 1)][0]  # [Wp, CW]
         for j in range(kw):
-            taps.append(
-                lax.slice(row, (j, 0), (j + stride * (ow - 1) + 1, cw),
-                          (stride, 1))
-            )  # [OW, CW]
-    return jnp.concatenate(taps, axis=-1)
+            tap = x_ref[0, oh_idx * stride + i,
+                        pl.ds(j, ow, stride=stride), :]      # [OW, CW]
+            row = (i * kw + j) * cw
+            xs_ref[row:row + cw, :] = tap.T
 
 
-def _popcount_dot(w, xmat, k_bits: int, *, word_group: int, accum: str):
-    """w [bd, KW] x xmat [OW, KW] -> exact ±1 dot, int32 [bd, OW]."""
+def _popcount_dot(wt_ref, xs_ref, k_bits: int, *, word_group: int,
+                  accum: str):
+    """wt [KW, bd] x xs [KW, OW] -> exact ±1 dot, int32 [bd, OW]."""
     if accum == "broadcast":
         # Legacy formulation (A/B benchmarking only).
-        xnor = ~(w[:, None, :] ^ xmat[None, :, :])  # [bd, OW, KW]
-        pc = lax.population_count(xnor).astype(jnp.int32)
-        acc = jnp.sum(pc, axis=-1)
+        xnor = ~(wt_ref[...].T[:, :, None] ^ xs_ref[...][None, :, :])
+        pc = lax.population_count(xnor).astype(jnp.int32)  # [bd, KW, OW]
+        acc = jnp.sum(pc, axis=1)
     else:
-        acc = accum_popcount_rows(w, xmat, word_group=word_group)
+        acc = accum_popcount_km(wt_ref, xs_ref, word_group=word_group)
     return 2 * acc - jnp.int32(k_bits)
 
 
-def _fused_direct_conv_kernel(
-    x_ref, w_ref, a_ref, b_ref, o_ref, *,
+def _direct_conv_kernel(
+    x_ref, wt_ref, *rest,
     kh: int, kw: int, stride: int, ow: int, k_bits: int,
-    word_group: int, accum: str,
+    word_group: int, accum: str, fused: bool,
 ):
-    xmat = _gather_windows(x_ref, pl.program_id(1), kh=kh, kw=kw,
-                           stride=stride, ow=ow)
-    dot = _popcount_dot(w_ref[...], xmat, k_bits, word_group=word_group,
+    if fused:
+        a_ref, b_ref, o_ref, xs_ref = rest
+    else:
+        o_ref, xs_ref = rest
+    _gather_windows(x_ref, xs_ref, pl.program_id(1), kh=kh, kw=kw,
+                    stride=stride, ow=ow)
+    dot = _popcount_dot(wt_ref, xs_ref, k_bits, word_group=word_group,
                         accum=accum)
-    # Same float op order as bitops.direct_conv_oracle / fused_xnor_layer
-    # so every conv_impl x engine pair is bit-exact vs the others.
-    y = a_ref[...] * dot.astype(jnp.float32) + b_ref[...]  # [bd, OW]
-    words = sign_repack_m(y)  # [bd/32, OW]
-    o_ref[...] = words.T[None, None]  # [1, 1, OW, bd/32]
+    if fused:
+        # Same float op order as bitops.direct_conv_oracle /
+        # fused_xnor_layer so every conv_impl x engine pair is bit-exact
+        # vs the others.
+        y = a_ref[...] * dot.astype(jnp.float32) + b_ref[...]  # [bd, OW]
+        o_ref[...] = sign_repack_m(y).T[None, None]  # [1, 1, OW, bd/32]
+    else:
+        o_ref[...] = dot.T[None, None]  # [1, 1, OW, bd]
 
 
-def _direct_conv_dot_kernel(
-    x_ref, w_ref, o_ref, *,
-    kh: int, kw: int, stride: int, ow: int, k_bits: int,
-    word_group: int, accum: str,
-):
-    xmat = _gather_windows(x_ref, pl.program_id(1), kh=kh, kw=kw,
-                           stride=stride, ow=ow)
-    dot = _popcount_dot(w_ref[...], xmat, k_bits, word_group=word_group,
-                        accum=accum)
-    o_ref[...] = dot.T[None, None]  # [1, 1, OW, bd]
-
-
-def _grid_and_specs(n, hp, wp_sp, cw, oh, ow, d_pad, block_d, kwords):
-    grid = (n, oh, d_pad // block_d)
-    x_spec = pl.BlockSpec((1, hp, wp_sp, cw), lambda ni, oi, di: (ni, 0, 0, 0))
-    w_spec = pl.BlockSpec((block_d, kwords), lambda ni, oi, di: (di, 0))
-    return grid, x_spec, w_spec
+def _direct_conv_call(wp, xpad, k_bits, a, b, *, kh, kw, stride, block_d,
+                      word_group, accum, interpret):
+    """The shared launch of both variants (``a``/``b`` None: the
+    epilogue-free dot)."""
+    n, hp, wp_sp, cw = xpad.shape
+    d_pad, kwords = wp.shape
+    assert kwords == kh * kw * cw, (wp.shape, kh, kw, cw)
+    assert d_pad % block_d == 0, (d_pad, block_d)
+    assert accum in ("loop", "broadcast"), accum
+    fused = a is not None
+    oh = (hp - kh) // stride + 1
+    ow = (wp_sp - kw) // stride + 1
+    kernel = functools.partial(
+        _direct_conv_kernel, kh=kh, kw=kw, stride=stride, ow=ow,
+        k_bits=k_bits, word_group=word_group, accum=accum, fused=fused,
+    )
+    # Filters go in word-major (popcount.py).
+    in_specs = [
+        pl.BlockSpec((1, hp, wp_sp, cw), lambda ni, oi, di: (ni, 0, 0, 0)),
+        pl.BlockSpec((kwords, block_d), lambda ni, oi, di: (0, di)),
+    ]
+    operands = [xpad, wp.T]
+    if fused:
+        assert block_d % PACK_BITS == 0, block_d
+        assert a.shape == (d_pad, 1) and b.shape == (d_pad, 1), (
+            a.shape, b.shape)
+        in_specs += [pl.BlockSpec((block_d, 1), lambda ni, oi, di: (di, 0))] * 2
+        operands += [a.astype(jnp.float32), b.astype(jnp.float32)]
+        out_words, out_d = block_d // PACK_BITS, d_pad // PACK_BITS
+    else:
+        out_words, out_d = block_d, d_pad
+    return pl.pallas_call(
+        kernel,
+        grid=(n, oh, d_pad // block_d),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec(
+            (1, 1, ow, out_words), lambda ni, oi, di: (ni, oi, 0, di),
+        ),
+        out_shape=jax.ShapeDtypeStruct((n, oh, ow, out_d), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((kwords, ow), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+        ),
+        interpret=interpret,
+    )(*operands)
 
 
 @functools.partial(
@@ -152,43 +189,11 @@ def fused_direct_conv(
     padded ``a=0, b=+1`` to pin their bits. ``block_d`` must divide by
     32 so each tile repacks to whole words.
     """
-    n, hp, wp_sp, cw = xpad.shape
-    d_pad, kwords = wp.shape
-    assert kwords == kh * kw * cw, (wp.shape, kh, kw, cw)
-    assert block_d % PACK_BITS == 0 and d_pad % block_d == 0, (d_pad, block_d)
-    assert a.shape == (d_pad, 1) and b.shape == (d_pad, 1), (a.shape, b.shape)
-    oh = (hp - kh) // stride + 1
-    ow = (wp_sp - kw) // stride + 1
-
-    assert accum in ("loop", "broadcast"), accum
-    kernel = functools.partial(
-        _fused_direct_conv_kernel, kh=kh, kw=kw, stride=stride, ow=ow,
-        k_bits=k_bits, word_group=word_group, accum=accum,
-    )
-    grid, x_spec, w_spec = _grid_and_specs(
-        n, hp, wp_sp, cw, oh, ow, d_pad, block_d, kwords
-    )
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            x_spec,
-            w_spec,
-            pl.BlockSpec((block_d, 1), lambda ni, oi, di: (di, 0)),
-            pl.BlockSpec((block_d, 1), lambda ni, oi, di: (di, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, ow, block_d // PACK_BITS),
-            lambda ni, oi, di: (ni, oi, 0, di),
-        ),
-        out_shape=jax.ShapeDtypeStruct(
-            (n, oh, ow, d_pad // PACK_BITS), jnp.int32
-        ),
-        compiler_params=pallas_compat.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel"),
-        ),
+    return _direct_conv_call(
+        wp, xpad, k_bits, a, b, kh=kh, kw=kw, stride=stride,
+        block_d=block_d, word_group=word_group, accum=accum,
         interpret=interpret,
-    )(xpad, wp, a.astype(jnp.float32), b.astype(jnp.float32))
+    )
 
 
 @functools.partial(
@@ -217,31 +222,8 @@ def direct_conv_dot(
     by the unfused PACKED path (bias/alpha/BN applied by the caller in
     float). Padded D rows produce garbage the wrapper slices off.
     """
-    n, hp, wp_sp, cw = xpad.shape
-    d_pad, kwords = wp.shape
-    assert kwords == kh * kw * cw, (wp.shape, kh, kw, cw)
-    assert d_pad % block_d == 0, (d_pad, block_d)
-    oh = (hp - kh) // stride + 1
-    ow = (wp_sp - kw) // stride + 1
-
-    assert accum in ("loop", "broadcast"), accum
-    kernel = functools.partial(
-        _direct_conv_dot_kernel, kh=kh, kw=kw, stride=stride, ow=ow,
-        k_bits=k_bits, word_group=word_group, accum=accum,
-    )
-    grid, x_spec, w_spec = _grid_and_specs(
-        n, hp, wp_sp, cw, oh, ow, d_pad, block_d, kwords
-    )
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[x_spec, w_spec],
-        out_specs=pl.BlockSpec(
-            (1, 1, ow, block_d), lambda ni, oi, di: (ni, oi, 0, di)
-        ),
-        out_shape=jax.ShapeDtypeStruct((n, oh, ow, d_pad), jnp.int32),
-        compiler_params=pallas_compat.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel"),
-        ),
+    return _direct_conv_call(
+        wp, xpad, k_bits, None, None, kh=kh, kw=kw, stride=stride,
+        block_d=block_d, word_group=word_group, accum=accum,
         interpret=interpret,
-    )(xpad, wp)
+    )

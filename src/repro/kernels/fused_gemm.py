@@ -46,7 +46,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bitops import PACK_BITS
-from repro.kernels import pallas_compat
 from repro.kernels.popcount import (
     DEFAULT_WORD_GROUP,
     accum_popcount_km,
@@ -55,22 +54,23 @@ from repro.kernels.popcount import (
 
 
 def _fused_xnor_gemm_kernel(
-    w_ref, x_ref, a_ref, b_ref, o_ref, acc_ref, *,
+    wt_ref, x_ref, a_ref, b_ref, o_ref, acc_ref, *,
     k_bits: int, nk: int, word_group: int, accum: str,
 ):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w = w_ref[...]  # [bm, bkw] int32 (packed)
-    x = x_ref[...]  # [bkw, bn] int32 (packed)
     if accum == "broadcast":
+        w = wt_ref[...].T  # [bm, bkw] int32 (packed)
+        x = x_ref[...]     # [bkw, bn] int32 (packed)
         # Legacy formulation (A/B benchmarking only).
         xnor = ~(w[:, :, None] ^ x[None, :, :])  # [bm, bkw, bn]
         pc = lax.population_count(xnor).astype(jnp.int32)
         acc_ref[...] += jnp.sum(pc, axis=1)
     else:
-        acc_ref[...] += accum_popcount_km(w, x, word_group=word_group)
+        acc_ref[...] += accum_popcount_km(wt_ref, x_ref,
+                                          word_group=word_group)
 
     @pl.when(pl.program_id(2) == nk - 1)
     def _epilogue():
@@ -126,7 +126,7 @@ def fused_xnor_gemm(
         kernel,
         grid=(m // block_m, n // block_n, nk),
         in_specs=[
-            pl.BlockSpec((block_m, block_kw), lambda i, j, k: (i, k)),
+            pl.BlockSpec((block_kw, block_m), lambda i, j, k: (k, i)),
             pl.BlockSpec((block_kw, block_n), lambda i, j, k: (k, j)),
             pl.BlockSpec((block_m, 1), lambda i, j, k: (i, 0)),
             pl.BlockSpec((block_m, 1), lambda i, j, k: (i, 0)),
@@ -136,8 +136,8 @@ def fused_xnor_gemm(
         ),
         out_shape=jax.ShapeDtypeStruct((m // PACK_BITS, n), jnp.int32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(wp, xp, a.astype(jnp.float32), b.astype(jnp.float32))
+    )(wp.T, xp, a.astype(jnp.float32), b.astype(jnp.float32))  # word-major

@@ -14,8 +14,9 @@ Two kernels share the PR-1 epilogue (`popcount.sign_repack_m`) and the
 broadcast-free accumulators (`popcount.accum_popcount_*`):
 
 * :func:`megakernel_chain` — a GEMM chain (the FC trunk). Layer weights
-  are stacked into one padded ``[L, M_max, KW_max]`` tensor with
-  per-layer folded affines ``[L, M_max]``, ALL resident in VMEM across
+  are stacked into one padded ``[L, M_max, KW_max]`` tensor (handed to
+  the kernel word-major, ``[L, KW_max, M_max]``) with per-layer folded
+  affines ``[L, M_max]``, ALL resident in VMEM across
   the grid (their block index is constant, so the pipeline fetches them
   once). The grid tiles the batch (N) dimension only; a
   ``lax.fori_loop`` over layers runs xnor-popcount -> folded-BN affine
@@ -29,10 +30,11 @@ broadcast-free accumulators (`popcount.accum_popcount_*`):
 * :func:`megakernel_conv_stage` — a conv stage (conv [+ conv] +
   packed-OR maxpool) via the PR-2 direct-conv path: one program per
   image holds the whole spatially-pre-padded channel-packed map in
-  VMEM, gathers every 3x3 tap of the FULL image with static slices
-  (the im2col patch matrix never exists, not even in VMEM rows), and
-  chains the per-layer epilogues on in-register maps; only the pooled
-  packed map of the LAST conv is written back to HBM.
+  VMEM, stages every 3x3 tap of the FULL image as word rows in VMEM
+  scratch (the im2col patch matrix never reaches HBM), re-borders each
+  intermediate map in a VMEM scratch map, and ORs the last map's
+  stride-2 rows into the pooled output; only the pooled packed map of
+  the LAST conv is written back to HBM.
 
 Padding conventions are exactly PR-1's, applied per stacked layer:
 K-words past a layer's true ``kw`` are zero in the weights and
@@ -43,11 +45,14 @@ scratch buffer unchanged and every kernel takes TRUE ``k_bits``.
 
 VMEM budget (CIFAR BNN FC trunk, block_n=128):
   w stack   2*1024*256*4   = 2 MiB    (resident across the whole grid)
-  a, b      2*2*1024*4     = 16 KiB
+  a, b      2*2*1024*4     = 16 KiB   ([L, M, 1] columns, lane-padded)
   ping-pong 2*256*128*4    = 256 KiB
   acc/y     3*1024*128*4   = 1.5 MiB  (popcount word term, acc, f32 y)
   final     16*32*4 + out  = ~10 KiB
-~3.8 MiB of ~16 MiB VMEM; conv stages peak lower (§8 table).
+~3.8 MiB by this model; the TPU compiler's own count for a v5e is
+~4.8 MiB of scoped VMEM (the affine columns pad to 128 lanes), and
+every CIFAR conv stage needs less (`tests/test_tpu_compile.py`
+compiles them all).
 """
 
 from __future__ import annotations
@@ -61,19 +66,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bitops import PACK_BITS
-from repro.kernels import pallas_compat
 from repro.kernels.popcount import (
     DEFAULT_WORD_GROUP,
     accum_popcount_km,
     accum_popcount_km_dyn,
-    accum_popcount_rows,
     sign_repack_m,
 )
 
 
 def _chain_kernel(
-    w_ref, a_ref, b_ref, kb_ref, ng_ref, x_ref, *rest,
-    n_layers: int, kw_act: int, word_group: int, has_final: bool,
+    wt_ref, a_ref, b_ref, kb_ref, ng_ref, x_ref, *rest,
+    n_layers: int, word_group: int, has_final: bool,
     final_k_bits: int, masked: bool,
 ):
     if masked:
@@ -81,11 +84,12 @@ def _chain_kernel(
     else:
         nr_ref = None
     if has_final:
-        wf_ref, o_ref, buf_ref = rest
+        wft_ref, o_ref, buf_ref = rest
     else:
-        wf_ref = None
+        wft_ref = None
         o_ref, buf_ref = rest
-    m_max = w_ref.shape[1]
+    rows = wt_ref.shape[2] // PACK_BITS            # packed words of M_max
+    kw_act = buf_ref.shape[1]
 
     # Stage the batch tile of packed input activations into ping-pong
     # slot 0; the loop alternates slots so layer l reads buf[l % 2] and
@@ -93,33 +97,35 @@ def _chain_kernel(
     buf_ref[0] = x_ref[...]
 
     def layer(l, carry):
-        act = buf_ref[l % 2]                       # [kw_act, bn]
-        w = w_ref[l]                               # [m_max, kw_max]
         # Dynamic trip count: a ragged layer walks ITS K-word groups,
         # not the stack-wide KW_max (pad groups would contribute zero
         # but still cost full-tile popcounts).
         acc = accum_popcount_km_dyn(
-            w, act[: w.shape[1]], ng_ref[l, 0], word_group=word_group
+            wt_ref, buf_ref, ng_ref[l], word_group=word_group,
+            w_lead=(l,), x_lead=(l % 2,),
         )
-        dot = (2 * acc - kb_ref[l, 0]).astype(jnp.float32)
-        y = a_ref[l][:, None] * dot + b_ref[l][:, None]
-        words = sign_repack_m(y)                   # [m_max/32, bn]
-        # Rows past m_max/32 must be all-ones (activation-pad words) for
-        # the next layer's zero weight words to be xnor-neutral.
-        nxt = jnp.full((kw_act, act.shape[1]), -1, jnp.int32)
-        buf_ref[(l + 1) % 2] = lax.dynamic_update_slice(nxt, words, (0, 0))
+        dot = (2 * acc - kb_ref[l]).astype(jnp.float32)
+        y = a_ref[l] * dot + b_ref[l]              # [m_max, bn]
+        nxt = (l + 1) % 2
+        buf_ref[nxt, pl.ds(0, rows), :] = sign_repack_m(y)  # [m_max/32, bn]
+        if kw_act > rows:
+            # Rows past m_max/32 must be all-ones (activation-pad words)
+            # for the next layer's zero weight words to be xnor-neutral.
+            buf_ref[nxt, pl.ds(rows, kw_act - rows), :] = jnp.full(
+                (kw_act - rows, buf_ref.shape[2]), -1, jnp.int32
+            )
         return carry
 
     lax.fori_loop(0, n_layers, layer, 0)
-    act = buf_ref[n_layers % 2]
+    last = n_layers % 2
     if has_final:
         # Float-boundary head: epilogue-free exact ±1 dot, same int32
         # result as a standalone xnor_gemm on the chain's output.
-        wf = wf_ref[...]                           # [mf_pad, kwf]
-        acc = accum_popcount_km(wf, act[: wf.shape[1]], word_group=word_group)
+        acc = accum_popcount_km(wft_ref, buf_ref, word_group=word_group,
+                                x_lead=(last,))
         out = 2 * acc - jnp.int32(final_k_bits)
     else:
-        out = act[: m_max // PACK_BITS]
+        out = buf_ref[last, pl.ds(0, rows), :]
     if masked:
         # Ragged masked tail (DESIGN.md §9): the batch extent is only
         # tile-padded, so the last grid step may hang past the true
@@ -130,7 +136,7 @@ def _chain_kernel(
         cols = pl.program_id(0) * bn + lax.broadcasted_iota(
             jnp.int32, (1, bn), 1
         )
-        out = jnp.where(cols < nr_ref[0, 0], out, 0)
+        out = jnp.where(cols < nr_ref[0], out, 0)
     o_ref[...] = out
 
 
@@ -200,32 +206,35 @@ def megakernel_chain(
 
     masked = n_real is not None
     kernel = functools.partial(
-        _chain_kernel, n_layers=l, kw_act=kw_act, word_group=word_group,
+        _chain_kernel, n_layers=l, word_group=word_group,
         has_final=has_final, final_k_bits=final_k_bits, masked=masked,
     )
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     in_specs = [
-        pl.BlockSpec((l, m_max, kw_max), lambda i: (0, 0, 0)),
-        pl.BlockSpec((l, m_max), lambda i: (0, 0)),
-        pl.BlockSpec((l, m_max), lambda i: (0, 0)),
-        pl.BlockSpec((l, 1), lambda i: (0, 0)),
-        pl.BlockSpec((l, 1), lambda i: (0, 0)),
+        pl.BlockSpec((l, kw_max, m_max), lambda i: (0, 0, 0)),
+        pl.BlockSpec((l, m_max, 1), lambda i: (0, 0, 0)),
+        pl.BlockSpec((l, m_max, 1), lambda i: (0, 0, 0)),
+        smem,
+        smem,
         pl.BlockSpec((kw_act, block_n), lambda i: (0, i)),
     ]
+    # Weights go in word-major (popcount.py); the affines as [L, M, 1]
+    # columns so a layer's row index yields its [M, 1] epilogue operand.
     operands = [
-        w_stack,
-        a_stack.astype(jnp.float32),
-        b_stack.astype(jnp.float32),
-        k_bits.astype(jnp.int32),
-        n_groups.astype(jnp.int32),
+        jnp.swapaxes(w_stack, 1, 2),
+        a_stack.astype(jnp.float32)[..., None],
+        b_stack.astype(jnp.float32)[..., None],
+        k_bits.astype(jnp.int32).reshape(l),
+        n_groups.astype(jnp.int32).reshape(l),
         xp,
     ]
     if masked:
         assert n_real.shape == (1, 1), n_real.shape
-        in_specs.append(pl.BlockSpec((1, 1), lambda i: (0, 0)))
-        operands.append(n_real.astype(jnp.int32))
+        in_specs.append(smem)
+        operands.append(n_real.astype(jnp.int32).reshape(1))
     if has_final:
-        in_specs.append(pl.BlockSpec((mf, kwf), lambda i: (0, 0)))
-        operands.append(final_wp)
+        in_specs.append(pl.BlockSpec((kwf, mf), lambda i: (0, 0)))
+        operands.append(final_wp.T)
     return pl.pallas_call(
         kernel,
         grid=(n // block_n,),
@@ -233,54 +242,77 @@ def megakernel_chain(
         out_specs=pl.BlockSpec((out_rows, block_n), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((out_rows, n), jnp.int32),
         scratch_shapes=[pltpu.VMEM((2, kw_act, block_n), jnp.int32)],
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
     )(*operands)
 
 
+def _stage_windows(src, lead: tuple, xs_ref, *, kh: int, kw: int, oh: int,
+                   ow: int):
+    """Gather every kH*kW tap of a whole padded map ``src[*lead] [Hp,
+    Wp, CW]`` (a VMEM ref) into ``xs_ref`` as word rows: row
+    ``(i*kW + j)*CW + cw`` holds tap (i, j), word cw of all ``oh*ow``
+    output pixels — the ``[KW, N]`` operand layout of
+    ``accum_popcount_km``, in the tap-major word order of the
+    pack_conv_aligned filters."""
+    cw = src.shape[-1]
+    for i in range(kh):
+        for j in range(kw):
+            tap = src[lead + (slice(i, i + oh), slice(j, j + ow))]  # [oh, ow, CW]
+            row = (i * kw + j) * cw
+            xs_ref[row:row + cw, :] = tap.reshape(oh * ow, cw).T
+
+
 def _conv_stage_kernel(
     *refs,
-    n_layers: int, kh: int, kw: int, k_bits: tuple[int, ...], pool: bool,
-    word_group: int,
+    n_layers: int, kh: int, kw: int, pad: int, k_bits: tuple[int, ...],
+    pool: bool, word_group: int,
 ):
     x_ref = refs[0]
-    o_ref = refs[-1]
-    mp = x_ref[0]  # [Hp, Wp, CW] — the whole padded map, in VMEM
+    o_ref = refs[1 + 3 * n_layers]
+    xs_ref, *maps = refs[2 + 3 * n_layers:]
+    # The whole padded map, in VMEM: x_ref[0] [Hp, Wp, CW], then each
+    # re-bordered intermediate map.
+    src, lead = x_ref, (0,)
     for l in range(n_layers):
-        w_ref, a_ref, b_ref = refs[1 + 3 * l : 4 + 3 * l]
-        hp, wp_sp, cw = mp.shape
-        cw_l = w_ref.shape[1] // (kh * kw)
+        wt_ref, a_ref, b_ref = refs[1 + 3 * l : 4 + 3 * l]
+        hp, wp_sp, _ = src.shape[len(lead):]
         oh, ow = hp - kh + 1, wp_sp - kw + 1
-        # Whole-image window gather: tap (i, j) of EVERY output pixel is
-        # one static slice of the map — tap-major word order
-        # (i*kW + j)*CW + cw, the pack_conv_aligned filter layout.
-        taps = [
-            lax.slice(mp, (i, j, 0), (i + oh, j + ow, cw_l))
-            for i in range(kh) for j in range(kw)
-        ]
-        xmat = jnp.concatenate(taps, axis=-1)
-        xmat = xmat.reshape(oh * ow, kh * kw * cw_l)
-        acc = accum_popcount_rows(w_ref[...], xmat, word_group=word_group)
+        dw = wt_ref.shape[1] // PACK_BITS
+        _stage_windows(src, lead, xs_ref, kh=kh, kw=kw, oh=oh, ow=ow)
+        acc = accum_popcount_km(wt_ref, xs_ref, word_group=word_group)
         dot = (2 * acc - jnp.int32(k_bits[l])).astype(jnp.float32)
         y = a_ref[...] * dot + b_ref[...]          # [d_pad, oh*ow]
-        words = sign_repack_m(y)                   # [d_pad/32, oh*ow]
-        mp = words.T.reshape(oh, ow, y.shape[0] // PACK_BITS)
+        words = sign_repack_m(y).T.reshape(oh, ow, dw)
+        dst = maps[l] if l < len(maps) else None
         if l + 1 < n_layers:
             # Re-grow the all-ones spatial border for the next conv —
             # in VMEM, never via HBM.
-            mp = jnp.pad(mp, ((1, 1), (1, 1), (0, 0)), constant_values=-1)
-    if pool:
-        # 2x2 packed maxpool = bitwise OR of the window words (§3).
-        mp = (mp[0::2, 0::2] | mp[0::2, 1::2]
-              | mp[1::2, 0::2] | mp[1::2, 1::2])
-    o_ref[...] = mp[None]
+            dst[...] = jnp.full(dst.shape, -1, jnp.int32)
+            dst[pad:pad + oh, pad:pad + ow, :] = words
+            src, lead = dst, ()
+        elif pool:
+            # 2x2 packed maxpool = bitwise OR of the window words (§3),
+            # read back as stride-2 rows of the staged output map.
+            dst[...] = words
+            for ph in range(oh // 2):
+                out = None
+                for di in (0, 1):
+                    for dj in (0, 1):
+                        v = dst[2 * ph + di, pl.ds(dj, ow // 2, stride=2), :]
+                        out = v if out is None else out | v
+                o_ref[0, ph] = out
+        else:
+            o_ref[0] = words
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("k_bits", "kh", "kw", "pool", "word_group", "interpret"),
+    static_argnames=(
+        "k_bits", "kh", "kw", "pad", "pool", "word_group", "interpret",
+    ),
 )
 def megakernel_conv_stage(
     xpad: jnp.ndarray,
@@ -291,6 +323,7 @@ def megakernel_conv_stage(
     k_bits: tuple[int, ...],
     kh: int = 3,
     kw: int = 3,
+    pad: int = 1,
     pool: bool = True,
     word_group: int = DEFAULT_WORD_GROUP,
     interpret: bool = False,
@@ -299,7 +332,8 @@ def megakernel_conv_stage(
     packed-OR maxpool) — in one launch, one program per image.
 
     ``xpad``: channel-packed map ``[N, Hp, Wp, CW]`` with its spatial
-    all-ones border already applied (stride 1; Hp = H + 2*pad).
+    all-ones border already applied (stride 1; Hp = H + 2*pad; every
+    later conv of the stage is re-bordered by ``pad`` in VMEM).
     ``weights[l]``: tap-aligned packed filters ``[D_pad_l, kH*kW*CW_l]``
     with ``D_pad_l % 32 == 0`` and ``CW_l`` = words/pixel of that
     layer's input (``CW_0 = CW``; ``CW_{l+1} = D_pad_l/32``).
@@ -318,8 +352,10 @@ def megakernel_conv_stage(
         assert kwords == kh * kw * cw_in, (l, wl.shape, kh, kw, cw_in)
         assert a[l].shape == (d_pad, 1) and b[l].shape == (d_pad, 1)
         cw_in = d_pad // PACK_BITS
-    d_pad_last = weights[-1].shape[0]
     oh, ow = hp - kh + 1, wp_sp - kw + 1
+    assert n_layers == 1 or (oh, ow) == (hp - 2 * pad, wp_sp - 2 * pad), (
+        "stage convs must preserve the spatial size", hp, wp_sp, pad)
+    dw_last = weights[-1].shape[0] // PACK_BITS
     out_h, out_w = (oh // 2, ow // 2) if pool else (oh, ow)
 
     in_specs = [pl.BlockSpec((1, hp, wp_sp, cw), lambda i: (i, 0, 0, 0))]
@@ -327,13 +363,24 @@ def megakernel_conv_stage(
     for wl, al, bl in zip(weights, a, b):
         d_pad, kwords = wl.shape
         in_specs += [
-            pl.BlockSpec((d_pad, kwords), lambda i: (0, 0)),
+            pl.BlockSpec((kwords, d_pad), lambda i: (0, 0)),
             pl.BlockSpec((d_pad, 1), lambda i: (0, 0)),
             pl.BlockSpec((d_pad, 1), lambda i: (0, 0)),
         ]
-        operands += [wl, al.astype(jnp.float32), bl.astype(jnp.float32)]
+        # word-major filters (popcount.py)
+        operands += [wl.T, al.astype(jnp.float32), bl.astype(jnp.float32)]
+    # VMEM scratch: the gathered window rows (shared by every layer —
+    # all convs of a stage see the same oh*ow pixels), the re-bordered
+    # map between convs, and the last conv's map staged for the pool.
+    kwords_max = max(wl.shape[1] for wl in weights)
+    scratch = [pltpu.VMEM((kwords_max, oh * ow), jnp.int32)]
+    for wl in weights[:-1]:
+        scratch.append(pltpu.VMEM(
+            (hp, wp_sp, wl.shape[0] // PACK_BITS), jnp.int32))
+    if pool:
+        scratch.append(pltpu.VMEM((oh, ow, dw_last), jnp.int32))
     kernel = functools.partial(
-        _conv_stage_kernel, n_layers=n_layers, kh=kh, kw=kw,
+        _conv_stage_kernel, n_layers=n_layers, kh=kh, kw=kw, pad=pad,
         k_bits=tuple(k_bits), pool=pool, word_group=word_group,
     )
     return pl.pallas_call(
@@ -341,13 +388,13 @@ def megakernel_conv_stage(
         grid=(n,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, out_h, out_w, d_pad_last // PACK_BITS),
-            lambda i: (i, 0, 0, 0),
+            (1, out_h, out_w, dw_last), lambda i: (i, 0, 0, 0),
         ),
         out_shape=jax.ShapeDtypeStruct(
-            (n, out_h, out_w, d_pad_last // PACK_BITS), jnp.int32
+            (n, out_h, out_w, dw_last), jnp.int32
         ),
-        compiler_params=pallas_compat.CompilerParams(
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

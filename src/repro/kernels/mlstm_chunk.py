@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
 
 NEG = -1e30
 
@@ -136,7 +135,7 @@ def mlstm_chunked(
             pltpu.VMEM((1, dk), jnp.float32),    # n
             pltpu.VMEM((1, 1), jnp.float32),     # m
         ],
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -157,7 +157,7 @@ def fused_xnor_gemm(
 
 
 def _pad_direct_conv_operands(wp, xp, pad, kh, kw, stride, block_d,
-                              word_group, *, fused, kernel):
+                              word_group, *, kernel):
     """Spatial all-ones border + D padding for the direct-conv kernels.
 
     Returns (wp_p, xpad, d, block_d, word_group): ``block_d`` resolves
@@ -173,7 +173,6 @@ def _pad_direct_conv_operands(wp, xp, pad, kh, kw, stride, block_d,
     ow = (wp_sp - kw) // stride + 1
     block_d, word_group = autotune.resolve_conv_block_d(
         kernel, d, hp, wp_sp, cw, kh, kw, ow, block_d, word_group,
-        fused=fused,
     )
     pd = -d % block_d
     wp_p = jnp.pad(wp, ((0, pd), (0, 0))) if pd else wp
@@ -211,7 +210,7 @@ def fused_direct_conv(
     interpret = _default_interpret() if interpret is None else interpret
     wp_p, xpad, d, block_d, word_group = _pad_direct_conv_operands(
         wp, xp, pad, kh, kw, stride, block_d, word_group,
-        fused=True, kernel="fused_direct_conv",
+        kernel="fused_direct_conv",
     )
     pd = wp_p.shape[0] - d
     a_p = jnp.pad(a.astype(jnp.float32), (0, pd))[:, None]
@@ -249,7 +248,7 @@ def direct_conv(
     interpret = _default_interpret() if interpret is None else interpret
     wp_p, xpad, d, block_d, word_group = _pad_direct_conv_operands(
         wp, xp, pad, kh, kw, stride, block_d, word_group,
-        fused=False, kernel="direct_conv",
+        kernel="direct_conv",
     )
     out = direct_kernel.direct_conv_dot(
         wp_p, xpad, k_bits,
@@ -272,9 +271,9 @@ def pack_rows(
     if k % PACK_BITS != 0:
         raise ValueError(f"K={k} must be a multiple of {PACK_BITS}")
     kw = k // PACK_BITS
-    bkw = min(block_kw, kw) if kw % min(block_kw, kw) == 0 else 1
-    while kw % bkw:
-        bkw -= 1
+    # A word tile that does not divide KW would leave a ragged last
+    # block; the whole KW is always a legal tile.
+    bkw = block_kw if kw % block_kw == 0 else kw
     pn = -n % block_n
     x_p = jnp.pad(x, ((0, 0), (0, pn))) if pn else x
     out = pack_kernel.pack_rows(
@@ -434,7 +433,7 @@ def megakernel_conv_stage(
                            constant_values=1.0)[:, None])
     return mega_kernel.megakernel_conv_stage(
         xp, tuple(ws), tuple(aps), tuple(bps),
-        k_bits=tuple(k_bits), kh=kh, kw=kw, pool=pool,
+        k_bits=tuple(k_bits), kh=kh, kw=kw, pad=pad, pool=pool,
         word_group=int(word_group), interpret=interpret,
     )
 

@@ -12,10 +12,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels import pallas_compat
 
 from repro.core.bitops import PACK_BITS
 
@@ -25,8 +24,8 @@ def _pack_kernel(x_ref, o_ref):
     bk, bn = x.shape
     bkw = bk // PACK_BITS
     bits = (x >= 0).astype(jnp.int32).reshape(bkw, PACK_BITS, bn)
-    shifts = jnp.arange(PACK_BITS, dtype=jnp.int32)
-    o_ref[...] = jnp.sum(bits << shifts[None, :, None], axis=1)
+    shifts = lax.broadcasted_iota(jnp.int32, (1, PACK_BITS, 1), 1)
+    o_ref[...] = jnp.sum(bits << shifts, axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("block_kw", "block_n", "interpret"))
@@ -49,7 +48,7 @@ def pack_rows(
         ],
         out_specs=pl.BlockSpec((block_kw, block_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((kw, n), jnp.int32),
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

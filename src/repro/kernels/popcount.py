@@ -11,28 +11,28 @@ per iteration, unrolled inside the loop body so the VPU always has a
 full-tile op in flight), and a static tail handles
 ``k_words % word_group != 0`` exactly.
 
-Both layouts the kernels use are covered:
-
-* :func:`accum_popcount_km` — GEMM layout, ``w [M, KW]`` x ``x [KW, N]``
-* :func:`accum_popcount_rows` — gathered-window layout, ``w [M, KW]`` x
-  ``x [N, KW]`` (rows share the word axis; used by the direct conv)
+The accumulators read their operands from refs, in one layout: weights
+word-major ``wt [KW, M]``, activations ``x [KW, N]``. A word group is
+then a sublane-row load on both refs, the one dynamic slice the TPU
+lowering accepts at any word offset (a lane-axis slice must start on a
+128-word boundary); the group's ``[g, M]`` weight rows are transposed
+in-register so each word's ``[M, 1]`` column is a static lane slice.
+Kernels whose weights arrive as ``[M, KW]`` transpose them in XLA
+before the launch, and the direct-conv kernels stage their gathered
+windows as ``[KW, N]`` rows in VMEM scratch.
 
 ``word_group`` trades loop trip count against code size; it never
 affects results (asserted against the broadcast formulation in
 ``tests/test_kernels.py``), so the autotuner sweeps it like any other
-block dimension. When ``word_group >= k_words`` the fori_loop (and its
-traced-start dynamic slice) disappears entirely and the walk is a pure
-static unroll — the form to prefer if Mosaic ever rejects or
-pessimizes the dynamic minor-axis slice on a native TPU lowering
-(untested off-interpret in this container; the autotune candidate grid
-includes a full-unroll config so a measured sweep on real hardware
-picks whichever actually wins).
+block dimension. When ``word_group >= k_words`` the fori_loop
+disappears and the walk is a pure static unroll.
 """
 
 from __future__ import annotations
 
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
 
 from repro.core.bitops import PACK_BITS
 
@@ -48,8 +48,8 @@ def sign_repack_m(y: jnp.ndarray) -> jnp.ndarray:
     m, n = y.shape
     bits = (y >= 0).astype(jnp.int32)
     bits = bits.reshape(m // PACK_BITS, PACK_BITS, n)
-    shifts = jnp.arange(PACK_BITS, dtype=jnp.int32)
-    return jnp.sum(bits << shifts[None, :, None], axis=1)
+    shifts = lax.broadcasted_iota(jnp.int32, (1, PACK_BITS, 1), 1)
+    return jnp.sum(bits << shifts, axis=1)
 
 
 def _word_pc(w_col: jnp.ndarray, x_row: jnp.ndarray) -> jnp.ndarray:
@@ -57,49 +57,70 @@ def _word_pc(w_col: jnp.ndarray, x_row: jnp.ndarray) -> jnp.ndarray:
     return lax.population_count(~(w_col ^ x_row)).astype(jnp.int32)
 
 
-def accum_popcount_km(
-    w: jnp.ndarray, x: jnp.ndarray, *, word_group: int = DEFAULT_WORD_GROUP
-) -> jnp.ndarray:
-    """``sum_k popcount(~(w[:, k, None] ^ x[None, k, :]))`` -> [M, N].
+def _accum_group(wt_ref, x_ref, w_lead, x_lead, start, size: int, acc):
+    """Add ``size`` consecutive words starting at ``start`` (static or
+    traced) to ``acc``.
 
-    w: [M, KW] packed int32; x: [KW, N] packed int32. Only 2-D
-    intermediates exist: the loop body slices ``word_group`` words and
-    adds one ``[M, N]`` popcount per word (statically unrolled).
+    Both refs are read with sublane-row loads — the only dynamic slice
+    Mosaic lowers at any word offset — and the ``[size, M]`` weight
+    rows are transposed in-register so each word's ``[M, 1]`` column
+    comes from a static lane slice.
     """
-    m, kw = w.shape
-    _, n = x.shape
+    rows = (pl.ds(start, size), slice(None))
+    w = wt_ref[w_lead + rows].T                   # [M, size]
+    x = x_ref[x_lead + rows]                      # [size, N]
+    for i in range(size):
+        acc = acc + _word_pc(w[:, i : i + 1], x[i : i + 1, :])
+    return acc
+
+
+def accum_popcount_km(
+    wt_ref, x_ref, *, word_group: int = DEFAULT_WORD_GROUP,
+    w_lead: tuple = (), x_lead: tuple = (),
+) -> jnp.ndarray:
+    """``sum_k popcount(~(wt[k, :, None] ^ x[k, None, :]))`` -> [M, N].
+
+    ``wt_ref[*w_lead]``: word-major packed weights ``[KW, M]``;
+    ``x_ref[*x_lead]``: packed activations ``[>= KW, N]`` (rows past
+    ``KW`` are not read). The leading indices select a sub-array at
+    load time instead of through a ref view, which the TPU lowering
+    refuses on a lane-padded buffer (minor dim < 128). Only 2-D
+    intermediates exist: a ``fori_loop`` walks ``word_group``-word
+    groups (statically unrolled inside), then a static tail covers
+    ``KW % word_group``.
+    """
+    kw, m = wt_ref.shape[len(w_lead):]
+    n = x_ref.shape[-1]
+    g = max(1, min(word_group, kw))
     acc = jnp.zeros((m, n), jnp.int32)
-    if word_group >= kw:  # fully static unroll: no loop, no dynamic slice
-        for t in range(kw):
-            acc = acc + _word_pc(w[:, t : t + 1], x[t : t + 1, :])
-        return acc
-    g = max(1, word_group)
 
-    def body(t, acc):
-        wg = lax.dynamic_slice_in_dim(w, t * g, g, axis=1)  # [M, g]
-        xg = lax.dynamic_slice_in_dim(x, t * g, g, axis=0)  # [g, N]
-        for i in range(g):
-            acc = acc + _word_pc(wg[:, i : i + 1], xg[i : i + 1, :])
-        return acc
+    def group(start, size, a):
+        return _accum_group(wt_ref, x_ref, w_lead, x_lead, start, size, a)
 
-    acc = lax.fori_loop(0, kw // g, body, acc)
-    for t in range((kw // g) * g, kw):  # static ragged tail, still 2-D
-        acc = acc + _word_pc(w[:, t : t + 1], x[t : t + 1, :])
+    full = kw // g
+    if full == 1:
+        acc = group(0, g, acc)
+    elif full > 1:
+        acc = lax.fori_loop(0, full, lambda t, a: group(t * g, g, a), acc)
+    if kw % g:
+        acc = group(full * g, kw % g, acc)
     return acc
 
 
 def accum_popcount_km_dyn(
-    w: jnp.ndarray,
-    x: jnp.ndarray,
+    wt_ref,
+    x_ref,
     n_groups: jnp.ndarray,
     *,
     word_group: int = DEFAULT_WORD_GROUP,
+    w_lead: tuple = (),
+    x_lead: tuple = (),
 ) -> jnp.ndarray:
     """:func:`accum_popcount_km` with a TRACED trip count: walk only the
     first ``n_groups * word_group`` packed K-words of the operands.
 
     This is the megakernel-chain accumulator (DESIGN.md §8): layers of
-    different true K share one padded ``[L, M_max, KW_max]`` weight
+    different true K share one padded ``[L, KW_max, M_max]`` weight
     stack, and a per-layer ``n_groups = ceil(ceil(k/32) / word_group)``
     keeps each ``lax.fori_loop`` layer iteration from paying the
     stack-wide KW_max — a ragged layer walks its own K only. Words
@@ -107,59 +128,22 @@ def accum_popcount_km_dyn(
     pairs (zero weight words against all-ones activation words — the
     stacking convention guarantees this), so the group-aligned
     overshoot contributes exactly zero. ``KW`` must divide by
-    ``word_group`` and ``n_groups * word_group <= KW`` (else the
-    clamped dynamic slice would double-count the tail).
+    ``word_group`` and ``n_groups * word_group <= KW``.
     """
-    m, kw = w.shape
-    _, n = x.shape
+    kw, m = wt_ref.shape[len(w_lead):]
+    n = x_ref.shape[-1]
     g = max(1, word_group)
     assert kw % g == 0, (kw, g)
-
-    def body(t, acc):
-        wg = lax.dynamic_slice_in_dim(w, t * g, g, axis=1)  # [M, g]
-        xg = lax.dynamic_slice_in_dim(x, t * g, g, axis=0)  # [g, N]
-        for i in range(g):
-            acc = acc + _word_pc(wg[:, i : i + 1], xg[i : i + 1, :])
-        return acc
-
-    return lax.fori_loop(0, n_groups, body, jnp.zeros((m, n), jnp.int32))
-
-
-def accum_popcount_rows(
-    w: jnp.ndarray, x: jnp.ndarray, *, word_group: int = DEFAULT_WORD_GROUP
-) -> jnp.ndarray:
-    """Row-major sibling: w [M, KW] x x [N, KW] -> [M, N].
-
-    Same reduction as :func:`accum_popcount_km` with the second operand
-    carrying its word axis last (the layout the direct-conv window
-    gather produces), so no transpose/relayout is needed in-kernel.
-    """
-    m, kw = w.shape
-    n, _ = x.shape
-    acc = jnp.zeros((m, n), jnp.int32)
-    if word_group >= kw:  # fully static unroll: no loop, no dynamic slice
-        for t in range(kw):
-            acc = acc + _word_pc(w[:, t : t + 1], x[:, t][None, :])
-        return acc
-    g = max(1, word_group)
-
-    def body(t, acc):
-        wg = lax.dynamic_slice_in_dim(w, t * g, g, axis=1)  # [M, g]
-        xg = lax.dynamic_slice_in_dim(x, t * g, g, axis=1)  # [N, g]
-        for i in range(g):
-            acc = acc + _word_pc(wg[:, i : i + 1], xg[:, i][None, :])
-        return acc
-
-    acc = lax.fori_loop(0, kw // g, body, acc)
-    for t in range((kw // g) * g, kw):
-        acc = acc + _word_pc(w[:, t : t + 1], x[:, t][None, :])
-    return acc
+    return lax.fori_loop(
+        0, n_groups,
+        lambda t, a: _accum_group(wt_ref, x_ref, w_lead, x_lead, t * g, g, a),
+        jnp.zeros((m, n), jnp.int32),
+    )
 
 
 __all__ = [
     "DEFAULT_WORD_GROUP",
     "accum_popcount_km",
     "accum_popcount_km_dyn",
-    "accum_popcount_rows",
     "sign_repack_m",
 ]

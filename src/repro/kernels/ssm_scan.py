@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
 
 
 def _ssm_scan_kernel(dt_ref, xh_ref, b_ref, c_ref, a_ref, h0_ref,
@@ -86,7 +85,7 @@ def ssm_scan_chunk(
             jax.ShapeDtypeStruct((b, c, di), jnp.float32),
             jax.ShapeDtypeStruct((b, di, ds), jnp.float32),
         ],
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

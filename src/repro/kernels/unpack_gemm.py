@@ -22,26 +22,29 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels import pallas_compat
 
 from repro.core.bitops import PACK_BITS
 
 
-def _unpack_gemm_kernel(w_ref, x_ref, o_ref, acc_ref, *, nk: int):
+def _unpack_gemm_kernel(wt_ref, x_ref, o_ref, acc_ref, *, nk: int):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w_words = w_ref[...]  # [bm, bkw] int32
-    bm, bkw = w_words.shape
-    shifts = jnp.arange(PACK_BITS, dtype=jnp.int32)
-    bits = (w_words[:, :, None] >> shifts[None, None, :]) & 1  # [bm, bkw, 32]
-    w = (2 * bits - 1).reshape(bm, bkw * PACK_BITS).astype(x_ref.dtype)
+    # Word-major tile [bkw, bm]: each word unpacks along the sublane
+    # axis, so the K-major ±1 tile [bkw*32, bm] is a sublane merge (a
+    # lane-axis unpack would need a lane reshape the TPU cannot lower).
+    wt = wt_ref[...]
+    bkw, bm = wt.shape
+    shifts = lax.broadcasted_iota(jnp.int32, (1, PACK_BITS, 1), 1)
+    bits = (wt[:, None, :] >> shifts) & 1                      # [bkw, 32, bm]
+    w = (2 * bits - 1).reshape(bkw * PACK_BITS, bm).astype(x_ref.dtype)
     # MXU contraction with fp32 accumulation.
-    acc_ref[...] += jnp.dot(w, x_ref[...], preferred_element_type=jnp.float32)
+    acc_ref[...] += jnp.dot(w.T, x_ref[...],
+                            preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == nk - 1)
     def _done():
@@ -75,14 +78,14 @@ def unpack_gemm(
         kernel,
         grid=(m // block_m, n // block_n, nk),
         in_specs=[
-            pl.BlockSpec((block_m, block_kw), lambda i, j, k_: (i, k_)),
+            pl.BlockSpec((block_kw, block_m), lambda i, j, k_: (k_, i)),
             pl.BlockSpec((block_k, block_n), lambda i, j, k_: (k_, j)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k_: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(wp, x)
+    )(wp.T, x)  # word-major weights

@@ -31,16 +31,25 @@ def make_serving_mesh(n_devices: int | None = None) -> jax.sharding.Mesh:
     each device runs the whole network on its batch slice (DESIGN.md
     §10).
 
-    Simulated scale-out uses forced host devices exactly like the
-    dry-run path: set ``XLA_FLAGS=--xla_force_host_platform_device_
-    count=N`` BEFORE the first jax backend touch (``tests/conftest.py``
-    and ``benchmarks/scaling.py`` both do).
+    On an accelerator the mesh takes the host's chips; asking for more
+    than it has is an error that names them. Simulated scale-out on the
+    CPU uses forced host devices exactly like the dry-run path: set
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` BEFORE the
+    first jax backend touch (``tests/conftest.py`` and
+    ``benchmarks/scaling.py`` both do).
     """
     devices = jax.devices()
     n = len(devices) if n_devices is None else int(n_devices)
     if n < 1:
         raise ValueError(f"serving mesh needs >= 1 device, got {n}")
     if len(devices) < n:
+        platform = devices[0].platform
+        if platform != "cpu":
+            raise RuntimeError(
+                f"need {n} devices for the serving mesh, but this host has "
+                f"{len(devices)} {platform} device(s) "
+                f"({devices[0].device_kind})"
+            )
         raise RuntimeError(
             f"need {n} devices for the serving mesh, have {len(devices)}"
             " — simulated scale-out must set XLA_FLAGS=--xla_force_"

@@ -11,8 +11,10 @@ wait via ``--slo-ms``).
 Two modes:
 
 * ``--smoke`` (default) — a short fixed burst of ragged requests:
-  warms every bucket/extent, verifies per-request logits against a
-  direct exact-shape forward, prints the stats snapshot. CI runs this.
+  warms every bucket/extent, verifies per-request logits bit for bit
+  against the xla oracle engine at the request's exact shape, prints
+  the stats snapshot, and exits non-zero on any mismatch, unserved
+  request, retry or engine fallback.
 * ``--sustained`` — an open-loop load run: requests with random image
   counts arrive at ``--rate`` req/s for ``--duration`` seconds (real
   clock); the engine's dispatch loop runs in the gaps. Reports p50/p95/
@@ -25,7 +27,7 @@ every device, each dispatch's batch sharded over ``data``. Off-TPU the
 devices are simulated — the flag forces
 ``--xla_force_host_platform_device_count=N`` into ``XLA_FLAGS`` before
 the first jax backend touch (so it must not be combined with code that
-already initialized jax in-process).
+already initialized jax in-process); on a TPU it takes the host's chips.
 
   PYTHONPATH=src python -m repro.launch.serve_bnn --smoke
   PYTHONPATH=src python -m repro.launch.serve_bnn --smoke --devices 8
@@ -36,19 +38,22 @@ already initialized jax in-process).
 from __future__ import annotations
 
 import argparse
+import importlib.util
 import json
 import os
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.bnn import (
-    bnn_apply_fused,
+    bnn_serve_fn,
     init_bnn_params,
     pack_bnn_params_fused,
     pack_bnn_params_megakernel,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import (
     DEFAULT_BUCKETS,
     ContinuousServingEngine,
@@ -61,11 +66,24 @@ from repro.serve import (
 )
 
 
+def _cpu_backend() -> bool:
+    """Whether jax will run on the CPU, decided before its first backend
+    touch (the only time a host-device count can still be set):
+    ``JAX_PLATFORMS`` names the CPU first, or no TPU runtime is
+    installed."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms:
+        return platforms.split(",")[0].strip() == "cpu"
+    return importlib.util.find_spec("libtpu") is None
+
+
 def _force_host_devices(n: int) -> None:
-    """Simulated scale-out: force ``n`` host platform devices. Must run
-    before the first jax backend touch; a pre-set count in XLA_FLAGS
-    (e.g. the CI leg's environment) wins."""
-    if n <= 1:
+    """Simulated scale-out on the CPU: force ``n`` host platform
+    devices. Must run before the first jax backend touch; a pre-set
+    count in XLA_FLAGS (e.g. the CI leg's environment) wins. On a TPU
+    the mesh takes the host's chips (``launch.mesh.make_serving_mesh``
+    names them when there are too few)."""
+    if n <= 1 or not _cpu_backend():
         return
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" in flags:
@@ -190,40 +208,38 @@ def run_smoke(args) -> dict:
     eng.drain()
 
     # Verify the engine's core contract on the smoke traffic: per-request
-    # logits are bit-identical to running that request's images alone.
+    # logits are bit-identical to the xla oracle engine running that
+    # request's images alone (exact shape, no batching, no kernels).
+    oracle = bnn_serve_fn(engine="xla")
+    oracle_params = pack_bnn_params_fused(
+        init_bnn_params(jax.random.PRNGKey(args.seed))
+    )
     mismatches = 0
     errored = 0
     for rid, imgs in zip(rids, requests):
         got = eng.take(rid)
-        if got is not None and is_error(got):
-            # terminal resilience marker (deadline/retries) — possible
-            # only when --deadline-ms is set tight; not a divergence
+        if got is None or is_error(got):
+            # unserved, or a terminal resilience marker
+            # (deadline/retries): the smoke fails on either below
             errored += 1
             continue
-        if args.engine.startswith("megakernel"):
-            from repro.core.bnn import bnn_apply_megakernel
-
-            inner = "xnor" if args.engine == "megakernel" else "xla"
-            want = np.asarray(
-                bnn_apply_megakernel(eng.executors.packed, imgs,
-                                     engine=inner)
-            )
-        else:
-            want = np.asarray(
-                bnn_apply_fused(eng.executors.packed, imgs,
-                                engine=args.engine,
-                                conv_impl=args.conv_impl)
-            )
-        if got is None or not np.array_equal(got, want):
+        want = np.asarray(oracle(oracle_params, jnp.asarray(imgs)))
+        if not np.array_equal(got, want):
             mismatches += 1
     snap = eng.snapshot()
+    disp = snap["dispatch"]
     print(f"served {snap['requests']['completed']} requests "
           f"({snap['requests']['images_completed']} images), "
-          f"{mismatches} logits mismatches, {errored} expired/failed")
+          f"{mismatches} logits mismatches vs the xla oracle, "
+          f"{errored} unserved/expired/failed, {disp['retries']} "
+          f"retries, {disp['fallbacks']} fallbacks")
     print(json.dumps(snap, indent=2))
-    if mismatches:
-        raise SystemExit(f"{mismatches} requests diverged from the "
-                         "exact-shape forward")
+    if mismatches or errored or disp["retries"] or disp["fallbacks"]:
+        raise SystemExit(
+            f"smoke failed: {mismatches} requests diverged from the xla "
+            f"oracle, {errored} were not served, {disp['retries']} "
+            f"retries, {disp['fallbacks']} engine fallbacks"
+        )
     return snap
 
 
@@ -282,7 +298,7 @@ def run_sustained(args) -> dict:
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--engine", default="xla",
+    ap.add_argument("--engine", default="megakernel",
                     choices=["xla", "xnor", "megakernel", "megakernel_xla"],
                     help="xla/xnor: per-layer fused chain (pure-XLA "
                          "fallback, CPU-fast / Pallas, interpret "
@@ -350,6 +366,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
     _force_host_devices(args.devices)
+    enable_compile_cache()
     if args.buckets is None:
         # Smoke keeps the ladder small so warmup + the per-request
         # exact-shape verification forwards stay CI-cheap.

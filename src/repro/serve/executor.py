@@ -6,8 +6,8 @@ steady-state traffic is pure cache hits and the compile count equals
 the number of distinct buckets warmed (asserted in
 ``tests/test_serve.py`` and recorded in BENCH_serving.json).
 
-The executors run :func:`repro.core.bnn.bnn_serve_fn` — the jit'd,
-donation-annotated fused packed pipeline — so when ``blocks="auto"``
+The executors run :func:`repro.core.bnn.bnn_serve_fn` — the jit'd
+fused packed pipeline — so when ``blocks="auto"``
 each Pallas launch inside the traced program resolves its tiles through
 the PR-3 autotune cache (``kernels/autotune.py``): a ladder warmed once
 on a machine with a populated cache compiles straight to the tuned

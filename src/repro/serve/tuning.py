@@ -92,8 +92,6 @@ def tune_serving_blocks(
     from repro.core.bnn import bnn_serve_fn  # local: avoid import cycle
     from repro.serve.executor import IMAGE_SHAPE
 
-    # A fresh operand per call: serve_fn donates its images buffer on
-    # accelerators, so a captured array would die on the first call.
     def operand():
         return jnp.zeros((bucket,) + IMAGE_SHAPE, jnp.float32)
 

@@ -298,7 +298,6 @@ def make_dp_train_step(
             f"unknown grad_compression {grad_compression!r}; expected one "
             f"of {DP_COMPRESSIONS}"
         )
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     axis = "data"
@@ -345,11 +344,11 @@ def make_dp_train_step(
         }
         return new_params, new_adam, new_err, out_metrics
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         shard_step, mesh=mesh,
         in_specs=(P(), P(), P(axis), P(axis)),
         out_specs=(P(), P(), P(axis), P()),
-        check_rep=False,
+        check_vma=False,
     )
 
     def train_step(params, opt_state, err, batch):

@@ -160,6 +160,8 @@ def test_heuristic_blocks_fit_budget_and_alignment():
         if fused:
             assert cfg.block_m % PACK_BITS == 0
         assert cfg.block_kw <= max(kw, 1)
+        # every block a TPU-legal tile: (8, 128) multiples or whole dims
+        assert autotune.gemm_blocks_legal(m, kw, n, cfg, fused=fused)
 
 
 def test_resolve_clamps_blocks_to_tiny_shapes(cache_file):

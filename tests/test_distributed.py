@@ -159,8 +159,6 @@ def test_compression_single_round_is_int8_coarse():
 def test_psum_compressed_in_shard_map():
     if jax.device_count() < 1:
         pytest.skip("no devices")
-    from jax.experimental.shard_map import shard_map
-
     mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
     g = jnp.arange(8, dtype=jnp.float32)
 
@@ -168,7 +166,7 @@ def test_psum_compressed_in_shard_map():
         mean, err = compression.psum_compressed(g, jnp.zeros_like(g), "data")
         return mean
 
-    out = shard_map(f, mesh=mesh, in_specs=P(), out_specs=P())(g)
+    out = jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P())(g)
     np.testing.assert_allclose(out, g, atol=0.05)
 
 
@@ -215,8 +213,6 @@ def test_signsgd_single_round_is_scaled_sign():
 
 
 def test_psum_signsgd_in_shard_map():
-    from jax.experimental.shard_map import shard_map
-
     mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
     g = jnp.arange(8, dtype=jnp.float32) - 3.5
 
@@ -224,7 +220,7 @@ def test_psum_signsgd_in_shard_map():
         mean, err = compression.psum_signsgd(g, jnp.zeros_like(g), "data")
         return mean, err
 
-    mean, err = shard_map(f, mesh=mesh, in_specs=P(), out_specs=P())(g)
+    mean, err = jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P())(g)
     # single device: mean == scale * sign(g), and EF reconstructs g
     scale = float(jnp.mean(jnp.abs(g)))
     np.testing.assert_allclose(
@@ -242,8 +238,6 @@ def test_signsgd_convergence_tracks_fp32():
     1-bit EF-sign-SGD. Both compressed runs must reach (near) the fp32
     baseline's final loss: error feedback is exactly what makes 1-bit
     gradients usable, and this is the test that would catch losing it."""
-    from jax.experimental.shard_map import shard_map
-
     n_dev, n, d, lr, steps = 2, 64, 8, 0.05, 300
     rng = np.random.default_rng(3)
     w_true = rng.normal(size=(d,)).astype(np.float32)
@@ -258,11 +252,11 @@ def test_signsgd_convergence_tracks_fp32():
             g, new_err = reduce_fn(g, err[0])
             return w - lr * g, new_err[None]
 
-        step = jax.jit(shard_map(
+        step = jax.jit(jax.shard_map(
             shard_step, mesh=mesh,
             in_specs=(P(), P("data"), P("data"), P("data")),
             out_specs=(P(), P("data")),
-            check_rep=False,
+            check_vma=False,
         ))
         w = jnp.zeros((d,))
         err = jnp.zeros((n_dev, d))

@@ -10,6 +10,10 @@ tests/golden/bnn_trained_ckpt.npz — a sign-form checkpoint
 is the point: a random init exercises the same kernels but not the
 same stakes.
 
+The input images come from NumPy's generator
+(``np.random.default_rng(image_seed)``), never the jax PRNG, whose
+defaults change between jax releases.
+
 The fixture is EXACT by design. Two legitimate reasons it can move:
 
 * an intentional numerics change — regenerate with
@@ -26,6 +30,7 @@ import json
 import pathlib
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -65,11 +70,11 @@ def seeded(golden):
     )
     ckpt = FIXTURE.parent.parent.parent / data["checkpoint"]
     params = load_binary_checkpoint(ckpt)
-    images = jax.random.normal(
-        jax.random.PRNGKey(data["image_seed"]),
-        tuple(data["shape"][:1]) + (32, 32, 3),
-    )
-    return params, images
+    rng = np.random.default_rng(data["image_seed"])
+    images = rng.standard_normal(
+        tuple(data["shape"][:1]) + (32, 32, 3)
+    ).astype(np.float32)
+    return params, jnp.asarray(images)
 
 
 def test_checkpoint_format_tag():
