@@ -532,7 +532,7 @@ def run(smoke: bool = False, verbose: bool = True, write: bool = True) -> dict:
               f"{ss['compiles_under_traffic']} under traffic")
         bt = traffic["snapshot"]["batches"]
         print(f"traffic: buckets {bt['per_bucket']} | padding "
-              f"{bt['padding_overhead']:.1%}")
+              f"{bt['pad_row_fraction']:.1%}")
     if write:
         write_bench(BENCH_PATH, result, verbose=verbose)
     return result

@@ -382,29 +382,37 @@ def bnn_apply_megakernel(
     lcfg = BitLinearConfig(
         mode=QuantMode.FAKE_QUANT, binarize_acts=False, use_scale=use_scale
     )
-    x = bit_conv2d(packed["conv"][0], images, lcfg, stride=1, pad=1)
-    x = _batchnorm(packed["bn_conv0"], x, training=False)
-    xp = bitops.pack_bits(x, axis=-1)  # [N, H, W, C/32]
+    # Each forward stage under its own scope; the conv-stage launches
+    # carry their stage index in their name. No scope name contains
+    # "conv_stage": a device trace finds those launches by that text.
+    with jax.named_scope("first_layer"):
+        x = bit_conv2d(packed["conv"][0], images, lcfg, stride=1, pad=1)
+        x = _batchnorm(packed["bn_conv0"], x, training=False)
+        xp = bitops.pack_bits(x, axis=-1)  # [N, H, W, C/32]
 
-    for stage in CONV_STAGES:
-        xp = megakernel_conv_stage(
-            [packed["conv"][i] for i in stage],
-            xp,
-            tuple(3 * 3 * CONV_CHANNELS[i][0] for i in stage),
-            pool=stage[-1] in POOL_AFTER,
-            engine=engine, blocks=blocks,
+    for s, stage in enumerate(CONV_STAGES, 1):
+        with jax.named_scope(f"stage{s}"):
+            xp = megakernel_conv_stage(
+                [packed["conv"][i] for i in stage],
+                xp,
+                tuple(3 * 3 * CONV_CHANNELS[i][0] for i in stage),
+                pool=stage[-1] in POOL_AFTER,
+                engine=engine, blocks=blocks,
+                name=f"megakernel_conv_stage{s}",
+            )
+
+    with jax.named_scope("fc_trunk"):
+        n = xp.shape[0]
+        xp = xp.reshape(n, -1)  # word order matches pack_linear's K order
+        y = megakernel_fc_chain(
+            packed["fc_stack"], xp,
+            tuple(fin for fin, _ in FC_SIZES[:-1]),
+            FC_SIZES[-2][1],
+            final=packed["fc_final"], final_k=FC_SIZES[-1][0],
+            engine=engine, blocks=blocks, ragged=ragged,
         )
-
-    n = xp.shape[0]
-    xp = xp.reshape(n, -1)  # word order matches pack_linear's K order
-    y = megakernel_fc_chain(
-        packed["fc_stack"], xp,
-        tuple(fin for fin, _ in FC_SIZES[:-1]),
-        FC_SIZES[-2][1],
-        final=packed["fc_final"], final_k=FC_SIZES[-1][0],
-        engine=engine, blocks=blocks, ragged=ragged,
-    )
-    return _batchnorm(packed["bn_fc_last"], y, training=False)
+    with jax.named_scope("final_bn"):
+        return _batchnorm(packed["bn_fc_last"], y, training=False)
 
 
 # Engines bnn_serve_fn (and thus the serving executor cache) accepts.

@@ -384,6 +384,7 @@ def megakernel_conv_stage(
     pool: bool = True,
     engine: str = "xnor",
     blocks: object = AUTO,
+    name: str = "megakernel_conv_stage",
 ) -> jnp.ndarray:
     """Run one conv stage — the stage's fused binary convs + packed-OR
     maxpool — in one launch (``engine="xnor"``) or via the chained
@@ -393,6 +394,7 @@ def megakernel_conv_stage(
     folded ``a``/``b``); ``xp``: ``[N, H, W, CW]`` channel-packed map.
     Bit-identical to running :func:`fused_bit_conv2d` per layer and
     ``maxpool2_packed`` — the intermediate maps just never reach HBM.
+    ``name`` names the Pallas launch (the xla engine has none).
     """
     from repro.kernels.autotune import megakernel_block_kwargs
 
@@ -404,7 +406,7 @@ def megakernel_conv_stage(
         kwargs.pop("block_n", None)  # batch grid is per-image already
         return kops.megakernel_conv_stage(
             xp, weights, a, b, tuple(k_bits), kh=kh, kw=kw, pad=pad,
-            pool=pool, **kwargs,
+            pool=pool, name=name, **kwargs,
         )
     if engine == "xla":
         return bitops.conv_stage_xla(

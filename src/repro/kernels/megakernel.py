@@ -246,6 +246,7 @@ def megakernel_chain(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
+        name="megakernel_fc_trunk",
     )(*operands)
 
 
@@ -312,6 +313,7 @@ def _conv_stage_kernel(
     jax.jit,
     static_argnames=(
         "k_bits", "kh", "kw", "pad", "pool", "word_group", "interpret",
+        "name",
     ),
 )
 def megakernel_conv_stage(
@@ -327,6 +329,7 @@ def megakernel_conv_stage(
     pool: bool = True,
     word_group: int = DEFAULT_WORD_GROUP,
     interpret: bool = False,
+    name: str = "megakernel_conv_stage",
 ) -> jnp.ndarray:
     """One conv stage — ``len(weights)`` fused direct convs (+ optional
     packed-OR maxpool) — in one launch, one program per image.
@@ -341,6 +344,8 @@ def megakernel_conv_stage(
     ``k_bits[l]``: TRUE ``kH*kW*C_l``. Returns the stage's packed
     output map ``[N, OH', OW', D_pad_last/32]`` (halved spatially when
     ``pool``). Filters/affines are VMEM-resident across the batch grid.
+    ``name`` is the launch's name in the compiled program and in a
+    device trace (the network's stages carry their index in it).
     """
     n, hp, wp_sp, cw = xpad.shape
     n_layers = len(weights)
@@ -398,4 +403,5 @@ def megakernel_conv_stage(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
+        name=name,
     )(*operands)

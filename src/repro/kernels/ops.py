@@ -401,6 +401,7 @@ def megakernel_conv_stage(
     pool: bool = True,
     word_group: int | str = AUTO,
     interpret: bool | None = None,
+    name: str = "megakernel_conv_stage",
 ) -> jnp.ndarray:
     """Padded, dispatching conv-stage megakernel (DESIGN.md §8): the
     stage's fused direct convs + packed-OR maxpool in ONE launch, one
@@ -434,7 +435,7 @@ def megakernel_conv_stage(
     return mega_kernel.megakernel_conv_stage(
         xp, tuple(ws), tuple(aps), tuple(bps),
         k_bits=tuple(k_bits), kh=kh, kw=kw, pad=pad, pool=pool,
-        word_group=int(word_group), interpret=interpret,
+        word_group=int(word_group), interpret=interpret, name=name,
     )
 
 

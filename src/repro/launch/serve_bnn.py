@@ -273,9 +273,11 @@ def run_sustained(args) -> dict:
     print(f"sustained[{snap['scheduler']}]: {submitted} requests "
           f"({rejected} rejected) over {args.duration:.0f}s "
           f"at {args.rate}/s target")
+    wait = snap["queue_wait_s"]
     print(f"throughput {snap['throughput']['images_per_s']:.1f} img/s | "
           f"latency p50 {lat['p50']*1e3:.0f}ms p95 {lat['p95']*1e3:.0f}ms "
-          f"p99 {lat['p99']*1e3:.0f}ms")
+          f"p99 {lat['p99']*1e3:.0f}ms | queue wait mean "
+          f"{wait['mean']*1e3:.1f}ms max {wait['max']*1e3:.1f}ms")
     print(f"dispatch shapes {bat['per_bucket']} | pad-row fraction "
           f"{bat['pad_row_fraction']:.1%} | compiles "
           f"{snap['executors']['compiles']} (steady state: 0 new)")

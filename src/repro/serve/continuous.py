@@ -47,6 +47,7 @@ import time
 from typing import Callable, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serve.engine import ServingEngine
 from repro.serve.executor import (
@@ -288,12 +289,16 @@ class ContinuousServingEngine(ServingEngine):
         run (pad waste = extent - real rows). Runs through the base
         engine's fault plan + NaN guard (`_execute_rows`); a faulted
         dispatch contributes no service observation."""
-        x = batch.assemble(self.batcher.requests)
-        extent = self.executors.extent_of(x.shape[0])
+        with TraceAnnotation("serve.assemble"):
+            x = batch.assemble(self.batcher.requests)
+        extent = self._extent(batch)
         t0 = self.clock()
         logits = self._execute_rows(x)
         self.batcher.note_service(extent, self.clock() - t0)
         return logits, extent
+
+    def _extent(self, batch) -> int:
+        return self.executors.extent_of(batch.rows)
 
     def _on_remesh(self) -> None:
         # The extent ladder is device-multiple-scaled; after an elastic

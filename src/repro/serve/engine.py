@@ -23,6 +23,18 @@ A `FaultPlan` injects deterministic failures for tests and the chaos
 benchmark. All of it is observable through `ServeStats`
 (``snapshot()["dispatch"|"mesh"|"degraded"]``) — resilience is never
 silent.
+
+Tracing: the dispatch path is marked with ``jax.profiler`` spans, which
+land on the profiler's host plane on the device trace's clock and cost
+about a microsecond each when no profiler runs. ``serve.step`` holds one
+``step()``; ``serve.dispatch`` one dispatch attempt (args ``dispatch``,
+the fault-plan index, ``rows`` and ``extent``), and inside it
+``serve.assemble``, ``serve.h2d``, ``serve.launch``, ``serve.wait`` (the
+host waiting for the device; only while a profiler records, as waiting
+apart from the copy costs throughput), ``serve.d2h`` and
+``serve.scatter`` (the NaN guard, then the scatter into requests);
+``serve.compile`` holds an executor's first call. ``ServeStats`` counts each request's queue wait,
+from ``submit`` to the first dispatch that carries its rows.
 """
 
 from __future__ import annotations
@@ -33,6 +45,7 @@ from collections import deque
 from typing import Callable, Optional, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serve.buckets import DEFAULT_BUCKETS, mesh_buckets
 from repro.serve.executor import IMAGE_SHAPE, ExecutorCache
@@ -202,9 +215,10 @@ class ServingEngine:
         """Run the flush rules once; dispatch any ready batches.
         Returns the request ids resolved by this call (completed,
         expired, or failed)."""
-        self._check_heartbeats()
-        resolved = self._expire()
-        return resolved + self._run(self.batcher.poll())
+        with TraceAnnotation("serve.step"):
+            self._check_heartbeats()
+            resolved = self._expire()
+            return resolved + self._run(self.batcher.poll())
 
     def drain(self) -> list[int]:
         """Flush and run everything still pending — including retried
@@ -294,9 +308,10 @@ class ServingEngine:
             logits = np.full_like(logits, np.nan)
         # Always-on guard: a silently corrupted kernel becomes a
         # retryable failure, never poisoned results.
-        if not np.isfinite(logits).all():
-            raise NaNLogits(f"non-finite logits at dispatch {idx} "
-                            f"(engine {engine})")
+        with TraceAnnotation("serve.scatter"):
+            if not np.isfinite(logits).all():
+                raise NaNLogits(f"non-finite logits at dispatch {idx} "
+                                f"(engine {engine})")
         return logits
 
     def _dispatch(self, batch) -> tuple[np.ndarray, int]:
@@ -304,9 +319,25 @@ class ServingEngine:
         dispatched_rows)`` — the rows the accelerator actually ran
         (bucket size here; tile-padded extent in the continuous
         subclass), which is what the pad-waste accounting records."""
-        x = batch.assemble(self.batcher.requests)
+        with TraceAnnotation("serve.assemble"):
+            x = batch.assemble(self.batcher.requests)
         logits = self._execute_rows(x)
         return logits, x.shape[0]
+
+    def _extent(self, batch) -> int:
+        """The rows the accelerator runs for ``batch``."""
+        return batch.bucket
+
+    def _note_queue_waits(self, batch) -> None:
+        """Count each live request's queue wait at the first dispatch
+        that carries its rows: once per request, whether it is split
+        over batches or its batch is retried."""
+        now = self.clock()
+        for seg in batch.segments:
+            req = self.batcher.requests.get(seg.rid)
+            if req is not None and req.t_dispatch is None:
+                req.t_dispatch = now
+                self.stats.on_queue_wait(now - req.t_submit)
 
     def _run(self, batches, force: bool = False) -> list[int]:
         """Enqueue freshly coalesced batches behind any retried work and
@@ -337,12 +368,21 @@ class ServingEngine:
             for seg in batch.segments
         ):
             return []  # every request cancelled/expired since batching
-        try:
-            logits, dispatched = self._dispatch(batch)
-        except Exception as err:  # noqa: BLE001 — resilience boundary
-            return self._on_failure(work, err)
-        self._engine_failures = 0
-        self.stats.on_dispatch(dispatched, batch.rows, batch.reason)
+        with TraceAnnotation("serve.dispatch", dispatch=self._dispatch_seq,
+                             rows=batch.rows, extent=self._extent(batch)):
+            self._note_queue_waits(batch)
+            try:
+                logits, dispatched = self._dispatch(batch)
+            except Exception as err:  # noqa: BLE001 — resilience boundary
+                return self._on_failure(work, err)
+            self._engine_failures = 0
+            self.stats.on_dispatch(dispatched, batch.rows, batch.reason)
+            with TraceAnnotation("serve.scatter"):
+                return self._scatter(batch, logits)
+
+    def _scatter(self, batch, logits: np.ndarray) -> list[int]:
+        """Copy a dispatch's logits into its requests' results; returns
+        the requests it completed."""
         now = self.clock()
         self.stats.mark_wall(now)
         done: list[int] = []

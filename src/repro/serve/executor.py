@@ -45,10 +45,12 @@ invariant is unchanged: one executable per (shape class x mesh) key.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Optional, Sequence
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.bnn import bnn_serve_fn
 from repro.kernels.ops import RAGGED_TILE_N
@@ -124,6 +126,14 @@ def blocks_key(blocks) -> str:
             f"-bkw{blocks.block_kw}-wg{blocks.word_group}")
 
 
+def _compile_span(built: bool, extent: int):
+    """``serve.compile`` around the first call of a just-built executor
+    (jit compiles on that call); nothing otherwise."""
+    if built:
+        return TraceAnnotation("serve.compile", extent=extent)
+    return contextlib.nullcontext()
+
+
 class ExecutorCache:
     """Lazy per-bucket executor map with hit/miss/compile accounting."""
 
@@ -192,13 +202,33 @@ class ExecutorCache:
         this pad only fires for out-of-ladder dispatch.
         """
         n = images.shape[0]
-        run_n = -(-n // self.devices) * self.devices
-        fn = self.get(run_n)
-        if run_n != n:
-            pad = np.zeros((run_n - n,) + images.shape[1:], images.dtype)
-            images = np.concatenate([np.asarray(images), pad], axis=0)
-        out = fn(self.packed, jnp.asarray(images))
-        return np.asarray(out)[:n]
+        return self._execute(images, -(-n // self.devices) * self.devices)
+
+    def _execute(self, images: np.ndarray, extent: int) -> np.ndarray:
+        """Run ``images`` at ``extent`` rows (bit-neutral zero rows
+        appended) and return the host logits of the rows passed in."""
+        n = images.shape[0]
+        built = self.key(extent) not in self._fns
+        fn = self.get(extent)
+        if extent != n:
+            with TraceAnnotation("serve.assemble"):
+                pad = np.zeros((extent - n,) + images.shape[1:], images.dtype)
+                images = np.concatenate([np.asarray(images), pad], axis=0)
+        with TraceAnnotation("serve.h2d"):
+            x = jnp.asarray(images)
+        with _compile_span(built, extent), TraceAnnotation("serve.launch"):
+            out = fn(self.packed, x)
+        if TraceAnnotation.is_enabled():
+            # A profiler is recording: time the device apart from the
+            # device-to-host copy. Waiting twice wakes this thread twice,
+            # which costs ~3% of a v5e's backlog images/s, so only a
+            # trace pays it; the copy is asked for first so that it
+            # still starts when the device finishes.
+            out.copy_to_host_async()
+            with TraceAnnotation("serve.wait"):
+                out.block_until_ready()
+        with TraceAnnotation("serve.d2h"):
+            return np.asarray(out)[:n]
 
     def _ctor_kwargs(self) -> dict:
         return dict(engine=self.engine, conv_impl=self.conv_impl,
@@ -225,11 +255,12 @@ class ExecutorCache:
         Returns the number of executors built by this call."""
         built = 0
         for b in buckets:
-            if self.key(b) not in self._fns:
-                built += 1
+            new = self.key(b) not in self._fns
+            built += new
             fn = self.get(b)
-            fn(self.packed, jnp.zeros((b,) + IMAGE_SHAPE,
-                                      jnp.float32)).block_until_ready()
+            with _compile_span(new, b):
+                fn(self.packed, jnp.zeros((b,) + IMAGE_SHAPE,
+                                          jnp.float32)).block_until_ready()
         return built
 
     @property
@@ -278,14 +309,7 @@ class RaggedExecutorCache(ExecutorCache):
 
         Returns host logits ``[n, num_classes]`` for the REAL rows only.
         """
-        n = images.shape[0]
-        extent = self.extent_of(n)
-        fn = self.get(extent)
-        if extent != n:
-            pad = np.zeros((extent - n,) + images.shape[1:], images.dtype)
-            images = np.concatenate([np.asarray(images), pad], axis=0)
-        out = fn(self.packed, jnp.asarray(images))
-        return np.asarray(out)[:n]
+        return self._execute(images, self.extent_of(images.shape[0]))
 
 
 __all__ = [

@@ -46,11 +46,13 @@ from repro.serve.buckets import (
 
 @dataclasses.dataclass
 class Request:
-    """One inference request: ``images [n, H, W, C]``."""
+    """One inference request: ``images [n, H, W, C]``. ``t_dispatch``
+    is when the first dispatch carrying its rows began (None before)."""
 
     rid: int
     images: np.ndarray
     t_submit: float
+    t_dispatch: Optional[float] = None
 
     @property
     def n(self) -> int:

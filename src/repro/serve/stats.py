@@ -13,16 +13,15 @@ Snapshot schema (``ServeStats.snapshot()``)::
                   "retried": int},        # requests touched by a retry
      "batches": {"dispatched": int, "real_rows": int, "padded_rows": int,
                  "dispatched_rows": int,           # real + padded
-                 "padding_overhead": float,        # padded / (real+padded)
                  "pad_row_fraction": float,        # padded / dispatched_rows
                  "per_bucket": {bucket: count},    # dispatch counts per
                                                    # bucket rung / extent
-                 "bucket_hit_rate": {bucket: fraction of dispatches},
                  "flush_reasons": {"full"|"max_wait"|"drain": count}},
      "executors": {"compiles": int, "hits": int, "misses": int,
                    "keys": [str, ...]},            # cache keys built
      "latency_s": {"count": int, "mean": float,
                    "p50": float, "p95": float, "p99": float, "max": float},
+     "queue_wait_s": {"count": int, "mean": float, "max": float},
      "throughput": {"images_per_s": float, "wall_s": float},
      "slo": {"slo_s": float | None, "images_within_slo": int,
              "goodput_images_per_s": float},       # within-SLO imgs / wall
@@ -43,7 +42,9 @@ when no SLO is configured).
 
 Latency is measured request-submit -> request-complete on the engine's
 (injectable) clock, so the deterministic tests drive it with a fake
-clock and the CLI with ``time.monotonic``.
+clock and the CLI with ``time.monotonic``. Queue wait, on the same
+clock, runs from submit to the start of the first dispatch that carries
+the request's rows, counted once per request.
 """
 
 from __future__ import annotations
@@ -99,6 +100,9 @@ class ServeStats:
     executor_misses: int = 0
     executor_keys: list = dataclasses.field(default_factory=list)
     latencies_s: list = dataclasses.field(default_factory=list)
+    queue_wait_s: float = 0.0         # summed over requests
+    queue_waits: int = 0
+    queue_wait_max_s: float = 0.0
     wall_start: Optional[float] = None
     wall_end: Optional[float] = None
 
@@ -120,6 +124,12 @@ class ServeStats:
         self.latencies_s.append(latency_s)
         if self.slo_s is not None and latency_s <= self.slo_s:
             self.images_within_slo += n_images
+
+    def on_queue_wait(self, seconds: float) -> None:
+        """A request's first dispatch began ``seconds`` after its submit."""
+        self.queue_wait_s += seconds
+        self.queue_waits += 1
+        self.queue_wait_max_s = max(self.queue_wait_max_s, seconds)
 
     def on_reject(self, n_images: int) -> None:
         """An admission-control rejection (continuous scheduler's
@@ -196,17 +206,10 @@ class ServeStats:
                 "real_rows": self.real_rows,
                 "padded_rows": self.padded_rows,
                 "dispatched_rows": total_rows,
-                "padding_overhead": (
-                    self.padded_rows / total_rows if total_rows else 0.0
-                ),
                 "pad_row_fraction": (
                     self.padded_rows / total_rows if total_rows else 0.0
                 ),
                 "per_bucket": dict(sorted(self.bucket_dispatches.items())),
-                "bucket_hit_rate": {
-                    b: c / self.dispatched_batches
-                    for b, c in sorted(self.bucket_dispatches.items())
-                } if self.dispatched_batches else {},
                 "flush_reasons": dict(sorted(self.flush_reasons.items())),
             },
             "executors": {
@@ -222,6 +225,12 @@ class ServeStats:
                 "p95": percentile(lat, 95),
                 "p99": percentile(lat, 99),
                 "max": max(lat) if lat else 0.0,
+            },
+            "queue_wait_s": {
+                "count": self.queue_waits,
+                "mean": (self.queue_wait_s / self.queue_waits
+                         if self.queue_waits else 0.0),
+                "max": self.queue_wait_max_s,
             },
             "throughput": {
                 "images_per_s": (

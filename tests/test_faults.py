@@ -352,6 +352,29 @@ def test_retry_exhaustion_fails_requests_and_engine_survives(fused_params,
         eng.take(rid2), oracle(fused_params, images[2:4]))
 
 
+def test_queue_wait_counted_once_when_the_batch_is_retried(fused_params,
+                                                          images):
+    """A retried batch's requests keep the wait up to its first attempt:
+    the redispatch after the backoff counts nothing more."""
+    clk = FakeClock()
+    eng = ContinuousServingEngine(
+        fused_params, engine="xla", max_rows=4, max_wait_s=0.0, clock=clk,
+        retry=RetryPolicy(max_attempts=3, backoff_base_s=1.0, jitter=0.0),
+        faults=FaultPlan([FaultSpec("raise", at=0)], sleep=clk.advance),
+    )
+    rid = eng.submit(images[:3])
+    clk.advance(0.3)
+    eng.step()                           # dispatch 0 raises; backoff
+    assert eng.stats.batch_retries == 1
+    clk.advance(2.0)
+    eng.step()                           # dispatch 1 serves
+    assert not is_error(eng.take(rid))
+    wait = eng.snapshot()["queue_wait_s"]
+    assert wait["count"] == 1
+    assert wait["mean"] == pytest.approx(0.3)
+    assert wait["max"] == pytest.approx(0.3)
+
+
 def test_failed_batch_does_not_strand_batchmates(fused_params, images):
     """Regression for the §11 bugfix: one poisoned batch completes its
     own riders as RequestFailed and the NEXT batch in the same pump

@@ -642,6 +642,29 @@ def test_continuous_engine_extent_accounting(fused_params):
     assert snap["slo"]["images_within_slo"] == 7
 
 
+def test_queue_wait_counted_once_for_a_split_request(fused_params):
+    """A request's queue wait runs from submit to the first dispatch
+    that carries its rows: the second batch of a split request adds
+    nothing; a request first dispatched in that batch adds its own."""
+    clk = FakeClock()
+    eng = ContinuousServingEngine(fused_params, engine="xla", max_rows=4,
+                                  max_wait_s=1.0, clock=clk)
+    a = eng.submit(np.zeros((6, 32, 32, 3), np.float32))
+    clk.advance(0.25)
+    eng.step()                           # full batch: 4 of a's 6 rows
+    clk.advance(0.5)
+    b = eng.submit(np.zeros((1, 32, 32, 3), np.float32))
+    clk.advance(0.5)                     # a has waited out max_wait
+    eng.step()                           # a's last 2 rows + b
+    assert eng.take(a) is not None and eng.take(b) is not None
+    assert eng.stats.dispatched_batches == 2
+    wait = eng.snapshot()["queue_wait_s"]
+    assert wait["count"] == 2
+    assert eng.stats.queue_wait_s == pytest.approx(0.25 + 0.5)
+    assert wait["mean"] == pytest.approx(0.375)
+    assert wait["max"] == pytest.approx(0.5)
+
+
 def test_continuous_engine_counts_rejections(fused_params):
     eng = ContinuousServingEngine(fused_params, engine="xla", max_rows=4,
                                   max_queue_rows=4, max_wait_s=10.0,

@@ -13,6 +13,7 @@ pytest-xdist only the worker that runs this file should.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -164,3 +165,15 @@ def test_megakernel_forward_compiles(spec, monkeypatch):
     fn = bnn.bnn_serve_fn(engine="megakernel")
     hlo = fn.lower(packed, spec((BATCH, 32, 32, 3), F32)).compile().as_text()
     _assert_kernel(hlo, len(bnn.CONV_STAGES) + 1)
+    # A device trace names each launch by its instruction: the stages
+    # carry their index, and no forward scope carries the text a trace
+    # reduction searches for ("conv_stage", "tpu_custom_call").
+    launches = re.findall(
+        r'%(\w+)\.\d+ = [^\n]*custom_call_target="tpu_custom_call"', hlo)
+    assert sorted(launches) == [
+        f"megakernel_conv_stage{s}"
+        for s in range(1, len(bnn.CONV_STAGES) + 1)] + ["megakernel_fc_trunk"]
+    scopes = set(re.findall(r'op_name="jit\([^)]*\)/([^/"]+)/', hlo))
+    assert {"first_layer", "stage1", "fc_trunk", "final_bn"} <= scopes
+    assert not any("conv_stage" in s or "tpu_custom_call" in s
+                   for s in scopes)
