@@ -41,6 +41,23 @@ every dispatched shape divides the mesh, and any out-of-ladder batch is
 padded with bit-neutral zero rows to the next device multiple (sliced
 back to exact rows) instead of crashing. The steady-state compile
 invariant is unchanged: one executable per (shape class x mesh) key.
+
+Placement contract of a meshed cache: every input of a launch already
+lives where the ``shard_map`` specs (``distributed.sharding.serve_specs``)
+want it, so a launch moves nothing between devices.
+
+* The packed weights are placed on the mesh once, replicated, when the
+  cache is built. ``rebuild()`` (mesh shrink, engine failover) places
+  again from the unplaced tree the cache was built from (``unplaced``),
+  never from the old mesh's copy.
+* Each batch goes from the host straight onto its shards: every device
+  receives its own rows, with no staging on one device.
+* ``warmup()`` builds its input through the same placement, so the
+  executable it compiles is the one the timed dispatches hit.
+
+``ServeStats.weight_placements`` and ``sharded_puts`` count both. A
+single-device cache (``mesh=None``) places nothing: weights as given,
+batch through ``jnp.asarray``.
 """
 
 from __future__ import annotations
@@ -48,9 +65,11 @@ from __future__ import annotations
 import contextlib
 from typing import Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
+from jax.sharding import NamedSharding
 
 from repro.core.bnn import bnn_serve_fn
 from repro.kernels.ops import RAGGED_TILE_N
@@ -147,8 +166,9 @@ class ExecutorCache:
         mesh: object = None,
         stats: Optional[ServeStats] = None,
     ):
-        from repro.distributed.sharding import mesh_devices
+        from repro.distributed.sharding import mesh_devices, serve_specs
 
+        self.unplaced = packed_params
         self.packed = packed_params
         self.engine = engine
         self.conv_impl = conv_impl
@@ -157,6 +177,12 @@ class ExecutorCache:
         self.devices = mesh_devices(mesh)
         self.stats = stats if stats is not None else ServeStats()
         self._fns: dict[tuple, object] = {}
+        if mesh is not None:
+            p_spec, x_spec, _ = serve_specs(mesh)
+            self._batch_sharding = NamedSharding(mesh, x_spec)
+            self.packed = jax.device_put(packed_params,
+                                         NamedSharding(mesh, p_spec))
+            self.stats.on_weight_placement()
 
     def _mesh_key(self) -> tuple:
         """Device-count key component — present only for meshed caches,
@@ -214,8 +240,10 @@ class ExecutorCache:
             with TraceAnnotation("serve.assemble"):
                 pad = np.zeros((extent - n,) + images.shape[1:], images.dtype)
                 images = np.concatenate([np.asarray(images), pad], axis=0)
-        with TraceAnnotation("serve.h2d"):
-            x = jnp.asarray(images)
+        with TraceAnnotation("serve.h2d", shards=self.devices):
+            x = self._put(images)
+        if self.mesh is not None:
+            self.stats.on_sharded_put()
         with _compile_span(built, extent), TraceAnnotation("serve.launch"):
             out = fn(self.packed, x)
         if TraceAnnotation.is_enabled():
@@ -230,6 +258,13 @@ class ExecutorCache:
         with TraceAnnotation("serve.d2h"):
             return np.asarray(out)[:n]
 
+    def _put(self, images: np.ndarray):
+        """The batch where the executor reads it: on the one device, or
+        each shard straight from the host onto its device of the mesh."""
+        if self.mesh is None:
+            return jnp.asarray(images)
+        return jax.device_put(images, self._batch_sharding)
+
     def _ctor_kwargs(self) -> dict:
         return dict(engine=self.engine, conv_impl=self.conv_impl,
                     blocks=self.blocks, mesh=self.mesh, stats=self.stats)
@@ -241,26 +276,28 @@ class ExecutorCache:
         (DESIGN.md §11).  The stats recorder is SHARED with the old
         cache, so compile/hit accounting stays continuous across a
         demotion or shrink; executables are not carried over (they are
-        specialized to the old engine/mesh)."""
+        specialized to the old engine/mesh), and the new cache places
+        the unplaced weights on its own mesh."""
         kw = self._ctor_kwargs()
         if engine is not None:
             kw["engine"] = engine
         if mesh is not _UNSET:
             kw["mesh"] = mesh
-        return type(self)(self.packed if packed is None else packed, **kw)
+        return type(self)(self.unplaced if packed is None else packed, **kw)
 
     def warmup(self, buckets: Sequence[int]) -> int:
-        """Compile every bucket ahead of traffic (zeros input; the
-        executable is shape-specialized, values are irrelevant).
-        Returns the number of executors built by this call."""
+        """Compile every bucket ahead of traffic (zeros input, placed
+        as a dispatch places its batch; the executable is specialized to
+        shape and placement, values are irrelevant). Returns the number
+        of executors built by this call."""
         built = 0
         for b in buckets:
             new = self.key(b) not in self._fns
             built += new
             fn = self.get(b)
+            x = self._put(np.zeros((b,) + IMAGE_SHAPE, np.float32))
             with _compile_span(new, b):
-                fn(self.packed, jnp.zeros((b,) + IMAGE_SHAPE,
-                                          jnp.float32)).block_until_ready()
+                fn(self.packed, x).block_until_ready()
         return built
 
     @property

@@ -28,7 +28,9 @@ Snapshot schema (``ServeStats.snapshot()``)::
      "dispatch": {"retries": int,                  # batch redispatches
                   "fallbacks": int,                # engine demotions
                   "engine_path": ["old->new", ...]},
-     "mesh": {"shrinks": int, "devices": int | None},
+     "mesh": {"shrinks": int, "devices": int | None,
+              "weight_placements": int,   # packed trees placed on a mesh
+              "sharded_puts": int},       # batches put onto their shards
      "degraded": bool}    # any fallback or mesh shrink happened
 
 ``scheduler`` labels which dispatch policy produced the numbers (the
@@ -89,6 +91,8 @@ class ServeStats:
     engine_path: list = dataclasses.field(default_factory=list)
     mesh_shrinks: int = 0
     mesh_devices: Optional[int] = None
+    weight_placements: int = 0
+    sharded_puts: int = 0
     images_within_slo: int = 0
     dispatched_batches: int = 0
     real_rows: int = 0
@@ -162,6 +166,16 @@ class ServeStats:
     def on_shrink(self, old_devices: int, new_devices: int) -> None:
         self.mesh_shrinks += 1
         self.mesh_devices = new_devices
+
+    def on_weight_placement(self) -> None:
+        """A meshed executor cache placed its packed weights on its mesh
+        (once at build: set-up, a shrink, a failover or its standby)."""
+        self.weight_placements += 1
+
+    def on_sharded_put(self) -> None:
+        """A dispatch put its batch from the host straight onto the
+        mesh's shards."""
+        self.sharded_puts += 1
 
     def on_executor(self, key: str, *, hit: bool, compiled: bool) -> None:
         if hit:
@@ -254,6 +268,8 @@ class ServeStats:
             "mesh": {
                 "shrinks": self.mesh_shrinks,
                 "devices": self.mesh_devices,
+                "weight_placements": self.weight_placements,
+                "sharded_puts": self.sharded_puts,
             },
             "degraded": bool(self.dispatch_fallbacks or self.mesh_shrinks),
         }

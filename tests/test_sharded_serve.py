@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.bnn import (
     bnn_apply_fused,
@@ -27,6 +28,7 @@ from repro.core.bnn import (
     pack_bnn_params_fused,
     pack_bnn_params_megakernel,
 )
+from repro.distributed.fault_tolerance import shrink_serving_mesh
 from repro.launch.mesh import make_serving_mesh
 from repro.serve import (
     ContinuousServingEngine,
@@ -303,3 +305,121 @@ def test_sharded_continuous_engine_bit_identical(fused_params):
     assert snap["executors"]["compiles"] == warmed
     # every dispatch ran at a mesh-divisible extent
     assert all(e % 8 == 0 for e in snap["batches"]["per_bucket"])
+
+
+# ---------------------------------------------------------------------------
+# placement: weights once per mesh, each batch straight onto its shards
+# ---------------------------------------------------------------------------
+
+# The megakernel packed tree (the served model's) through its pure-XLA
+# oracle engine, so the CPU runs it at XLA speed.
+PLACED_ENGINE = "megakernel_xla"
+
+
+def _placed_engine(kind, packed, mesh, **kw):
+    if kind == "bucket":
+        return ServingEngine(packed, engine=PLACED_ENGINE,
+                             buckets=(4, 8, 16), mesh=mesh,
+                             max_wait_s=0.0, **kw)
+    return ContinuousServingEngine(packed, engine=PLACED_ENGINE,
+                                   max_rows=16, mesh=mesh, max_wait_s=0.0,
+                                   **kw)
+
+
+def _serve_all(eng, batches):
+    rids = []
+    for x in batches:
+        rids.append(eng.submit(x))
+        eng.step()
+    eng.drain()
+    return [eng.take(r) for r in rids]
+
+
+def _assert_replicated_on(packed, mesh):
+    want = NamedSharding(mesh, P())
+    for leaf in jax.tree_util.tree_leaves(packed):
+        assert leaf.sharding == want
+
+
+def _batches(seed, sizes=(3, 1, 7, 16, 2, 9)):
+    rng = np.random.default_rng(seed)
+    return [rng.normal(size=(n, 32, 32, 3)).astype(np.float32)
+            for n in sizes]
+
+
+@pytest.mark.parametrize("kind", ["bucket", "continuous"])
+def test_meshed_dispatch_moves_nothing_between_devices(kind, mega_params):
+    """After warm-up, mixed-size dispatches on a 4-device mesh make no
+    implicit device-to-device transfer: the weights were placed on the
+    mesh once and every batch went from the host onto its shards. The
+    logits are those of the one-device engine, bit for bit, and the
+    executables warm-up compiled are the only ones traffic runs."""
+    mesh = make_serving_mesh(4)
+    eng = _placed_engine(kind, mega_params, mesh)
+    warmed = eng.warmup()
+    assert warmed == len(eng._warm_shapes())
+    _assert_replicated_on(eng.executors.packed, mesh)
+    batches = _batches(5)
+    with jax.transfer_guard_device_to_device("disallow"):
+        got = _serve_all(eng, batches)
+    want = _serve_all(_placed_engine(kind, mega_params, None), batches)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    snap = eng.snapshot()
+    assert snap["executors"]["compiles"] == warmed  # none under traffic
+    # the placed warm-up input hit the jit cache entry traffic uses
+    assert all(fn._cache_size() == 1 for fn in eng.executors._fns.values())
+    assert snap["mesh"]["sharded_puts"] == snap["batches"]["dispatched"] > 0
+    assert snap["mesh"]["weight_placements"] == 1
+
+
+def test_shrink_places_weights_on_the_new_mesh(mega_params):
+    """A heartbeat shrink 4 -> 2 places the weights again, from the
+    unplaced tree, on the survivors' mesh; the re-warmed dispatches make
+    no implicit transfer and stay bit-identical."""
+
+    class Clock:
+        t = 0.0
+
+        def __call__(self):
+            return self.t
+
+    clk = Clock()
+    mesh = make_serving_mesh(4)
+    eng = _placed_engine("continuous", mega_params, mesh,
+                         heartbeat_timeout_s=10.0, clock=clk)
+    eng.warmup()
+    clk.t = 5.0
+    for dev in (0, 1, 2):
+        eng.beat(dev)
+    clk.t = 12.0                       # device 3 silent past the timeout
+    batches = _batches(6, sizes=(5, 2, 8))
+    with jax.transfer_guard_device_to_device("disallow"):
+        got = _serve_all(eng, batches)
+    assert eng.executors.devices == 2
+    _assert_replicated_on(eng.executors.packed,
+                          shrink_serving_mesh(mesh, (3,)))
+    assert eng.executors.unplaced is mega_params
+    want = _serve_all(_placed_engine("continuous", mega_params, None),
+                      batches)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    snap = eng.snapshot()
+    assert snap["mesh"]["weight_placements"] == 2
+    assert snap["mesh"]["sharded_puts"] == snap["batches"]["dispatched"]
+
+
+def test_one_device_cache_places_nothing(mega_params):
+    """Without a mesh the cache runs the weights as given and puts each
+    batch on the one device: no placement, no sharded put, and the
+    logits of the plain jitted forward."""
+    cache = RaggedExecutorCache(mega_params, engine=PLACED_ENGINE)
+    assert cache.packed is mega_params
+    cache.warmup([4, 8])
+    fn = bnn_serve_fn(engine=PLACED_ENGINE, ragged=True)
+    for x in _batches(7, sizes=(3, 8)):
+        np.testing.assert_array_equal(
+            cache.run(x), np.asarray(fn(mega_params, jnp.asarray(x))))
+    mesh_stats = cache.stats.snapshot()["mesh"]
+    assert mesh_stats["sharded_puts"] == 0
+    assert mesh_stats["weight_placements"] == 0
