@@ -39,6 +39,9 @@ __all__ = [
     "fused_xnor_layer",
     "direct_conv_dot",
     "direct_conv_oracle",
+    "border_taps",
+    "border_correction",
+    "residual_conv_oracle",
     "maxpool2_packed",
     "megakernel_chain_xla",
     "conv_stage_xla",
@@ -277,6 +280,97 @@ def direct_conv_oracle(
                           pad=pad)
     y = a.astype(jnp.float32) * dot.astype(jnp.float32) + b.astype(jnp.float32)
     return pack_channels(y)
+
+
+def border_taps(size: int, k: int, stride: int, pad: int) -> list:
+    """The kernel offsets that fall in the padding, for the first, an
+    interior and the last output position along one spatial axis of a
+    ``size``-long input: the three border classes of that axis.
+
+    Only the first and last positions may touch the padding (checked),
+    which holds whenever ``pad < k`` and ``pad <= k - 1 - pad + stride``
+    — a 3x3, pad-1 conv at any stride. An axis of one or two outputs has
+    no interior position; its interior class is then empty."""
+    out = (size + 2 * pad - k) // stride + 1
+
+    def padded(p):
+        return frozenset(i for i in range(k)
+                         if not 0 <= p * stride + i - pad < size)
+
+    inner = {padded(p) for p in range(1, out - 1)}
+    if inner - {frozenset()}:
+        raise ValueError(f"interior outputs touch the padding: size={size}"
+                         f" k={k} stride={stride} pad={pad}")
+    return [padded(0), frozenset(), padded(out - 1)]
+
+
+def border_correction(w_pm1: jnp.ndarray, h: int, w: int, *, stride: int,
+                      pad: int) -> jnp.ndarray:
+    """``c_pos [3, D, 3]`` int32 of a ``kH x kW`` conv with +-1 filters
+    ``w_pm1 [D, kH, kW, C]`` over an ``h x w`` map: for each border
+    class (row class, col class; :func:`border_taps`), the sum of the
+    filter's +-1 weights over the taps that fall in the padding.
+
+    A packed conv pads with all-ones words, so each padded tap adds its
+    weights' sum to the dot; ``dot - c_pos`` is then the exact
+    zero-padded dot (the ``conv2d`` of a zero-padded map)."""
+    _, kh, kw, _ = w_pm1.shape
+    rows = border_taps(h, kh, stride, pad)
+    cols = border_taps(w, kw, stride, pad)
+    tap = jnp.sum(w_pm1, axis=-1)                      # [D, kH, kW]
+    out = []
+    for rset in rows:
+        for cset in cols:
+            mask = [[float(i in rset or j in cset) for j in range(kw)]
+                    for i in range(kh)]
+            out.append(jnp.sum(tap * jnp.asarray(mask, tap.dtype),
+                               axis=(1, 2)))
+    return jnp.round(jnp.stack(out, axis=-1)).astype(jnp.int32).reshape(
+        -1, 3, 3).transpose(1, 0, 2)
+
+
+def _border_map(cpos: jnp.ndarray, oh: int, ow: int) -> jnp.ndarray:
+    """``c_pos [3, D, 3]`` spread over the output map: ``[OH, OW, D]``."""
+    def classes(n):
+        return jnp.asarray([0] + [1] * max(n - 2, 0) + [2] * (n > 1))
+
+    per_row = cpos[classes(oh)]                        # [OH, D, 3]
+    return jnp.transpose(per_row[:, :, classes(ow)], (0, 2, 1))
+
+
+@functools.partial(
+    jax.jit, static_argnames=("k_bits", "kh", "kw", "stride", "pad")
+)
+def residual_conv_oracle(
+    wp: jnp.ndarray,
+    xp: jnp.ndarray,
+    k_bits: int,
+    a: jnp.ndarray,
+    b: jnp.ndarray,
+    cpos: jnp.ndarray,
+    r: jnp.ndarray,
+    *,
+    kh: int,
+    kw: int,
+    stride: int = 1,
+    pad: int = 0,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Direct conv with the residual epilogue, pure XLA (the oracle for
+    ``repro.kernels.direct_conv.residual_direct_conv``, and its
+    SPMD-safe fallback engine).
+
+    :func:`direct_conv_dot` (one-padded), less the border correction
+    ``c_pos`` (:func:`border_correction`), gives the zero-padded dot;
+    then ``y = a * dot + b + r`` in float32 with the f32 shortcut ``r
+    [N, OH, OW, D]``. Returns ``(y, packed sign(y))``: f32 ``[N, OH,
+    OW, D]`` and int32 ``[N, OH, OW, ceil(D/32)]``.
+    """
+    dot = direct_conv_dot(wp, xp, k_bits, kh=kh, kw=kw, stride=stride,
+                          pad=pad)
+    dot = dot - _border_map(cpos, dot.shape[1], dot.shape[2])
+    y = (a.astype(jnp.float32) * dot.astype(jnp.float32)
+         + b.astype(jnp.float32) + r.astype(jnp.float32))
+    return y, pack_channels(y)
 
 
 def maxpool2_packed(xp: jnp.ndarray) -> jnp.ndarray:

@@ -503,18 +503,9 @@ def bnn_serve_fn(
             )
 
     if mesh is not None:
-        from repro.distributed.sharding import serve_specs
+        from repro.distributed.sharding import shard_serving
 
-        p_spec, x_spec, y_spec = serve_specs(mesh)
-        # check_vma=False: the Pallas kernel calls inside the per-shard
-        # program carry no replication rules; correctness rests on the
-        # per-sample independence of the forward, asserted bit-exactly
-        # in the sharded test matrix.
-        apply_fn = jax.shard_map(
-            apply_fn, mesh=mesh,
-            in_specs=(p_spec, x_spec), out_specs=y_spec,
-            check_vma=False,
-        )
+        apply_fn = shard_serving(apply_fn, mesh)
 
     return jax.jit(apply_fn)
 
@@ -733,3 +724,8 @@ def load_binary_checkpoint(path: str) -> dict:
             })
             i += 1
     return params
+
+
+# Bi-Real Net-18's packer, so that the packer of every served network is
+# found in this module by the name a benchmark configuration gives.
+from repro.core.bireal import pack_bireal_params  # noqa: E402,F401

@@ -247,6 +247,21 @@ def serve_specs(mesh: Mesh) -> tuple[P, P, P]:
     return P(), P(axis), P(axis)
 
 
+def shard_serving(apply_fn, mesh: Mesh):
+    """Wrap a serving forward ``(packed, images) -> logits`` in
+    ``shard_map`` over ``mesh`` with :func:`serve_specs`: weights
+    replicated, batch and logits sharded over ``data``.
+
+    ``check_vma=False``: the Pallas kernel calls inside the per-shard
+    program carry no replication rules; correctness rests on the
+    per-sample independence of the forward, asserted bit-exactly in the
+    sharded test matrix (``tests/test_sharded_serve.py``).
+    """
+    p_spec, x_spec, y_spec = serve_specs(mesh)
+    return jax.shard_map(apply_fn, mesh=mesh, in_specs=(p_spec, x_spec),
+                         out_specs=y_spec, check_vma=False)
+
+
 # ------------------------- activation constraints -----------------------------
 #
 # Models are mesh-agnostic; the launcher installs the active mesh here and

@@ -8,6 +8,9 @@
 ``fused_direct_conv`` — direct packed-window conv + the same epilogue:
                         no im2col patch matrix in HBM (DESIGN.md §5).
 ``direct_conv``       — epilogue-free direct conv (int32 ±1 dot out).
+``bireal_block_conv`` — direct conv with a residual epilogue: the
+                        zero-padded dot (``c_pos`` border correction)
+                        plus an f32 shortcut, f32 AND packed out (§5.1).
 ``megakernel_chain``  — a whole chain of fused binary layers in ONE
                         launch: weights VMEM-resident, packed
                         activations ping-ponged in scratch (§8).
@@ -23,6 +26,7 @@ oracles live in :mod:`repro.kernels.ref` and :mod:`repro.core.bitops`.
 """
 
 from repro.kernels.ops import (  # noqa: F401
+    bireal_block_conv,
     direct_conv,
     fused_direct_conv,
     fused_xnor_gemm,

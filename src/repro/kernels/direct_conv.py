@@ -14,11 +14,15 @@ with the PR-1 fused epilogue (folded-BN affine -> sign -> repack along
 D). HBM sees: the packed map (read), the packed filters (read), the
 packed output (write). The patch matrix never exists.
 
-Two variants share the window gather:
+Three variants share the window gather:
 
-* ``fused_direct_conv`` — full fused layer, packed words in AND out,
-* ``direct_conv_dot``   — epilogue-free int32 ±1 dot ``[N,OH,OW,D]``
-                          (the chain-boundary / unfused-PACKED variant).
+* ``fused_direct_conv``    — full fused layer, packed words in AND out,
+* ``direct_conv_dot``      — epilogue-free int32 ±1 dot ``[N,OH,OW,D]``
+                             (the chain-boundary / unfused-PACKED variant),
+* ``residual_direct_conv`` — the residual epilogue of a Bi-Real block
+                             (DESIGN.md §5.1): the zero-padded dot via
+                             the ``c_pos`` border correction, plus an f32
+                             shortcut; f32 AND packed out.
 
 The popcount accumulation is BROADCAST-FREE (DESIGN.md §6): a
 ``lax.fori_loop`` over the kH*kW*CW packed filter words accumulates one
@@ -156,6 +160,117 @@ def _direct_conv_call(wp, xpad, k_bits, a, b, *, kh, kw, stride, block_d,
         ),
         interpret=interpret,
     )(*operands)
+
+
+def _border_correction(c_ref, row, *, oh: int, ow: int):
+    """``c_pos`` of one output row, ``[bd, OW]`` int32.
+
+    ``c_ref [3, bd, 3]`` holds the border classes (first / interior /
+    last output row x first / interior / last output column) of
+    :func:`repro.core.bitops.border_classes`; only the first and last
+    row and column of a 3x3, pad-1 conv touch the padding."""
+    cls = c_ref[jnp.where(row == 0, 0, jnp.where(row == oh - 1, 2, 1))]
+    col = lax.broadcasted_iota(jnp.int32, (cls.shape[0], ow), 1)
+    return jnp.where(col == 0, cls[:, 0:1],
+                     jnp.where(col == ow - 1, cls[:, 2:3], cls[:, 1:2]))
+
+
+def _residual_conv_kernel(
+    x_ref, wt_ref, a_ref, b_ref, c_ref, r_ref, y_ref, p_ref, xs_ref, *,
+    kh: int, kw: int, stride: int, oh: int, ow: int, k_bits: int,
+    word_group: int,
+):
+    row = pl.program_id(1)
+    _gather_windows(x_ref, xs_ref, row, kh=kh, kw=kw, stride=stride, ow=ow)
+    dot = _popcount_dot(wt_ref, xs_ref, k_bits, word_group=word_group,
+                        accum="loop")
+    dot = dot - _border_correction(c_ref, row, oh=oh, ow=ow)  # zero-padded
+    # Same float op order as bitops.residual_conv_oracle.
+    y = (a_ref[...] * dot.astype(jnp.float32) + b_ref[...]
+         + r_ref[0, 0].T)                                     # [bd, OW]
+    y_ref[...] = y.T[None, None]
+    p_ref[...] = sign_repack_m(y).T[None, None]
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("k_bits", "kh", "kw", "stride", "block_d",
+                     "word_group", "interpret", "name"),
+)
+def residual_direct_conv(
+    wp: jnp.ndarray,
+    xpad: jnp.ndarray,
+    k_bits: int,
+    a: jnp.ndarray,
+    b: jnp.ndarray,
+    cpos: jnp.ndarray,
+    r: jnp.ndarray,
+    *,
+    kh: int,
+    kw: int,
+    stride: int = 1,
+    block_d: int = 128,
+    word_group: int = DEFAULT_WORD_GROUP,
+    interpret: bool = False,
+    name: str = "bireal_block_conv",
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The direct conv with a residual epilogue (DESIGN.md §5.1): packed
+    map ``[N, Hp, Wp, CW]`` (all-ones border already applied) x
+    tap-aligned filters ``[D_pad, kH*kW*CW]`` ->
+
+        y = a * (dot - c_pos) + b + r        f32 [N, OH, OW, D_pad]
+        sign(y), repacked along D            int32 [N, OH, OW, D_pad/32]
+
+    ``dot`` is the one-padded popcount dot; ``c_pos [3, D_pad, 3]`` int32
+    (border classes) turns it into the exact zero-padded dot. ``a``/
+    ``b`` ``[D_pad, 1]`` f32, ``r`` the f32 shortcut ``[N, OH, OW,
+    D_pad]``; rows past the true D padded ``a=0, b=+1, c_pos=0, r=0``.
+    ``name`` names the launch in the compiled program and a trace.
+    """
+    n, hp, wp_sp, cw = xpad.shape
+    d_pad, kwords = wp.shape
+    assert kwords == kh * kw * cw, (wp.shape, kh, kw, cw)
+    assert d_pad % block_d == 0 and block_d % PACK_BITS == 0, (d_pad, block_d)
+    oh = (hp - kh) // stride + 1
+    ow = (wp_sp - kw) // stride + 1
+    assert a.shape == b.shape == (d_pad, 1), (a.shape, b.shape)
+    assert cpos.shape == (3, d_pad, 3), cpos.shape
+    assert r.shape == (n, oh, ow, d_pad), (r.shape, (n, oh, ow, d_pad))
+    kernel = functools.partial(
+        _residual_conv_kernel, kh=kh, kw=kw, stride=stride, oh=oh, ow=ow,
+        k_bits=k_bits, word_group=word_group,
+    )
+    per_d = pl.BlockSpec((block_d, 1), lambda ni, oi, di: (di, 0))
+    return pl.pallas_call(
+        kernel,
+        grid=(n, oh, d_pad // block_d),
+        in_specs=[
+            pl.BlockSpec((1, hp, wp_sp, cw), lambda ni, oi, di: (ni, 0, 0, 0)),
+            pl.BlockSpec((kwords, block_d), lambda ni, oi, di: (0, di)),
+            per_d,
+            per_d,
+            pl.BlockSpec((3, block_d, 3), lambda ni, oi, di: (0, di, 0)),
+            pl.BlockSpec((1, 1, ow, block_d),
+                         lambda ni, oi, di: (ni, oi, 0, di)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, ow, block_d),
+                         lambda ni, oi, di: (ni, oi, 0, di)),
+            pl.BlockSpec((1, 1, ow, block_d // PACK_BITS),
+                         lambda ni, oi, di: (ni, oi, 0, di)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((n, oh, ow, d_pad), jnp.float32),
+            jax.ShapeDtypeStruct((n, oh, ow, d_pad // PACK_BITS), jnp.int32),
+        ],
+        scratch_shapes=[pltpu.VMEM((kwords, ow), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+        ),
+        interpret=interpret,
+        name=name,
+    )(xpad, wp.T, a.astype(jnp.float32), b.astype(jnp.float32),
+      cpos.astype(jnp.int32), r.astype(jnp.float32))
 
 
 @functools.partial(

@@ -258,6 +258,55 @@ def direct_conv(
     return out[..., :d]
 
 
+def bireal_block_conv(
+    wp: jnp.ndarray,
+    xp: jnp.ndarray,
+    k_bits: int,
+    a: jnp.ndarray,
+    b: jnp.ndarray,
+    cpos: jnp.ndarray,
+    r: jnp.ndarray,
+    *,
+    kh: int = 3,
+    kw: int = 3,
+    stride: int = 1,
+    pad: int = 1,
+    block_d: int | str = AUTO,
+    word_group: int | str = AUTO,
+    interpret: bool | None = None,
+    name: str = "bireal_block_conv",
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Padded, dispatching direct conv with the residual epilogue
+    (DESIGN.md §5.1): ``y = a*(dot - c_pos) + b + r`` and ``sign(y)``.
+
+    Channel-packed map ``[N, H, W, CW]`` x tap-aligned filters ``[D,
+    kH*kW*CW]``, per-channel ``a, b [D]``, border classes ``cpos [3, D,
+    3]`` (``bitops.border_correction``) and the f32 shortcut ``r [N,
+    OH, OW, D]`` -> ``(y f32 [N, OH, OW, D], packed [N, OH, OW,
+    ceil(D/32)])``. The map pads with all-ones words; ``c_pos`` makes
+    the dot the zero-padded one. ``name`` names the launch.
+    """
+    if wp.dtype != PACKED_DTYPE or xp.dtype != PACKED_DTYPE:
+        raise TypeError(f"packed operands must be {PACKED_DTYPE}")
+    interpret = _default_interpret() if interpret is None else interpret
+    wp_p, xpad, d, block_d, word_group = _pad_direct_conv_operands(
+        wp, xp, pad, kh, kw, stride, block_d, word_group,
+        kernel="bireal_block_conv",
+    )
+    pd = wp_p.shape[0] - d
+    a_p = jnp.pad(a.astype(jnp.float32), (0, pd))[:, None]
+    b_p = jnp.pad(b.astype(jnp.float32), (0, pd), constant_values=1.0)[:, None]
+    if pd:
+        cpos = jnp.pad(cpos, ((0, 0), (0, pd), (0, 0)))
+        r = jnp.pad(r, ((0, 0), (0, 0), (0, 0), (0, pd)))
+    y, packed = direct_kernel.residual_direct_conv(
+        wp_p, xpad, k_bits, a_p, b_p, cpos, r,
+        kh=kh, kw=kw, stride=stride, block_d=block_d,
+        word_group=word_group, interpret=interpret, name=name,
+    )
+    return y[..., :d], packed[..., : -(-d // PACK_BITS)]
+
+
 def pack_rows(
     x: jnp.ndarray,
     *,
@@ -446,6 +495,7 @@ __all__ = [
     "fused_xnor_gemm",
     "fused_direct_conv",
     "direct_conv",
+    "bireal_block_conv",
     "megakernel_chain",
     "megakernel_conv_stage",
 ]

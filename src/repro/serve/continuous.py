@@ -55,6 +55,7 @@ from repro.serve.executor import (
     default_extents,
     extent_for,
 )
+from repro.serve.model import CIFAR_BNN, ServedModel
 from repro.serve.queue import MicroBatcher
 
 DEFAULT_MAX_ROWS = 32  # per-dispatch row budget (the ladder's top rung / 4)
@@ -215,6 +216,12 @@ class ContinuousServingEngine(ServingEngine):
     3-real-row batch on 8 devices dispatches at extent 8 and hands back
     exactly 3 rows. Per-request logits remain bit-identical to
     exact-shape single-device execution.
+
+    ``model`` (``serve.model.ServedModel``, default the CIFAR BNN) is
+    the network served: its serve fn, request shape and dtype, and
+    failover ladder — e.g. ``core.bireal.served_model()`` with
+    ``pack_bireal_params`` params and uint8 ``[n, 224, 224, 3]``
+    requests.
     """
 
     def __init__(
@@ -236,6 +243,7 @@ class ContinuousServingEngine(ServingEngine):
         faults=None,
         heartbeat_timeout_s: Optional[float] = None,
         clock: Callable[[], float] = time.monotonic,
+        model: ServedModel = CIFAR_BNN,
     ):
         # Deliberately NOT calling super().__init__: the base wires a
         # bucket MicroBatcher + bucket ExecutorCache; everything else
@@ -254,6 +262,7 @@ class ContinuousServingEngine(ServingEngine):
         self.executors = RaggedExecutorCache(
             packed_params, engine=engine, conv_impl=conv_impl,
             blocks=blocks, mesh=mesh, stats=self.stats,
+            model=model,
         )
         self.extents = default_extents(
             max_rows, tile=self.executors.tile,

@@ -48,10 +48,11 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.serve.buckets import DEFAULT_BUCKETS, mesh_buckets
-from repro.serve.executor import IMAGE_SHAPE, ExecutorCache
+from repro.serve.executor import ExecutorCache
 from repro.serve.faults import (DeadlineExceeded, DeviceLost, FallbackPolicy,
                                 FaultPlan, InjectedFault, NaNLogits,
                                 RequestFailed, RetryPolicy)
+from repro.serve.model import CIFAR_BNN, ServedModel
 from repro.serve.queue import MicroBatcher
 from repro.serve.stats import ServeStats
 
@@ -93,6 +94,9 @@ class ServingEngine:
     only) arms a `HeartbeatMonitor` — call ``beat(device)`` from the
     device-health source; a silent device triggers the same elastic
     shrink a mid-dispatch `DeviceLost` does.
+
+    ``model`` (``serve.model.ServedModel``) is the network served; the
+    default is the CIFAR BNN.
     """
 
     def __init__(
@@ -111,6 +115,7 @@ class ServingEngine:
         faults: Optional[FaultPlan] = None,
         heartbeat_timeout_s: Optional[float] = None,
         clock: Callable[[], float] = time.monotonic,
+        model: ServedModel = CIFAR_BNN,
     ):
         from repro.distributed.sharding import mesh_devices
 
@@ -122,7 +127,7 @@ class ServingEngine:
         )
         self.executors = ExecutorCache(
             packed_params, engine=engine, conv_impl=conv_impl,
-            blocks=blocks, mesh=mesh, stats=self.stats,
+            blocks=blocks, mesh=mesh, stats=self.stats, model=model,
         )
         # rid -> [n, 10] float logits being filled segment by segment
         self._partial: dict[int, np.ndarray] = {}
@@ -175,7 +180,8 @@ class ServingEngine:
         is armed or the ladder is exhausted)."""
         if self.fallback is None:
             return 0
-        nxt = self.fallback.next_engine(self.executors.engine)
+        nxt = self.fallback.next_engine(self.executors.engine,
+                                        self.executors.model.fallbacks)
         if nxt is None:
             return 0
         self._standby = self.executors.rebuild(
@@ -184,24 +190,21 @@ class ServingEngine:
 
     def submit(self, images: np.ndarray, *,
                deadline_s: Optional[float] = None) -> int:
-        """Enqueue one request of ``[n, 32, 32, 3]`` images.
+        """Enqueue one request of ``[n, *model.image_shape]`` images
+        (``[n, 32, 32, 3]`` float for the default CIFAR BNN).
 
-        The per-image shape is checked against the model's fixed input
-        HERE — the queue's own consistency check pins itself to the
-        FIRST request it sees, so without this a wrong-shaped first
-        request would be accepted, blow up mid-dispatch, and poison the
-        queue for every later (valid) request.
+        The per-image shape (and, for an integer model, the dtype) is
+        checked against the served model's input HERE — the queue's own
+        consistency check pins itself to the FIRST request it sees, so
+        without this a wrong-shaped first request would be accepted,
+        blow up mid-dispatch, and poison the queue for every later
+        (valid) request.
 
         ``deadline_s`` (falling back to the engine default) bounds how
         long the request may wait: past it, the request completes as a
         `DeadlineExceeded` result instead of being served late.
         """
-        images = np.asarray(images)
-        if images.shape[1:] != IMAGE_SHAPE:
-            raise ValueError(
-                f"request rows must be {IMAGE_SHAPE} images, got "
-                f"{images.shape[1:]}"
-            )
+        images = self.executors.model.check_images(images)
         rid = self.batcher.submit(images)
         self.stats.on_submit(self.batcher.requests[rid].n)
         self.stats.mark_wall(self.clock())
@@ -369,7 +372,8 @@ class ServingEngine:
         ):
             return []  # every request cancelled/expired since batching
         with TraceAnnotation("serve.dispatch", dispatch=self._dispatch_seq,
-                             rows=batch.rows, extent=self._extent(batch)):
+                             rows=batch.rows, extent=self._extent(batch),
+                             model=self.executors.model.name):
             self._note_queue_waits(batch)
             try:
                 logits, dispatched = self._dispatch(batch)
@@ -461,7 +465,8 @@ class ServingEngine:
             return
         if self._engine_failures < self.fallback.failures_before_demote:
             return
-        nxt = self.fallback.next_engine(self.executors.engine)
+        nxt = self.fallback.next_engine(self.executors.engine,
+                                        self.executors.model.fallbacks)
         if nxt is None:
             return
         old = self.executors.engine

@@ -6,8 +6,12 @@ steady-state traffic is pure cache hits and the compile count equals
 the number of distinct buckets warmed (asserted in
 ``tests/test_serve.py`` and recorded in BENCH_serving.json).
 
-The executors run :func:`repro.core.bnn.bnn_serve_fn` — the jit'd
-fused packed pipeline — so when ``blocks="auto"``
+The executors run the served model's serve fn (``model=``, a
+``serve.model.ServedModel``: serve fn, per-image shape and dtype,
+failover ladder). The default, ``CIFAR_BNN``, is
+:func:`repro.core.bnn.bnn_serve_fn` — the jit'd fused packed pipeline —
+and adds nothing to the cache keys; any other model adds its name.
+Warm-up inputs take the model's shape and dtype. When ``blocks="auto"``
 each Pallas launch inside the traced program resolves its tiles through
 the PR-3 autotune cache (``kernels/autotune.py``): a ladder warmed once
 on a machine with a populated cache compiles straight to the tuned
@@ -71,11 +75,9 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding
 
-from repro.core.bnn import bnn_serve_fn
 from repro.kernels.ops import RAGGED_TILE_N
+from repro.serve.model import CIFAR_BNN, ServedModel
 from repro.serve.stats import ServeStats
-
-IMAGE_SHAPE = (32, 32, 3)  # the CIFAR BNN's fixed per-image shape
 
 _UNSET = object()  # rebuild() sentinel: mesh=None is a meaningful override
 
@@ -165,9 +167,11 @@ class ExecutorCache:
         blocks: object = "auto",
         mesh: object = None,
         stats: Optional[ServeStats] = None,
+        model: ServedModel = CIFAR_BNN,
     ):
         from repro.distributed.sharding import mesh_devices, serve_specs
 
+        self.model = model
         self.unplaced = packed_params
         self.packed = packed_params
         self.engine = engine
@@ -191,13 +195,20 @@ class ExecutorCache:
         alias a single-device one of the same bucket shape."""
         return (f"mesh{self.devices}",) if self.mesh is not None else ()
 
+    def _model_key(self) -> tuple:
+        """Model key component — absent for the default CIFAR model, so
+        its keys are unchanged."""
+        return () if self.model == CIFAR_BNN else (self.model.name,)
+
     def key(self, bucket: int) -> tuple:
         return (bucket, self.engine, self.conv_impl,
-                blocks_key(self.blocks)) + self._mesh_key()
+                blocks_key(self.blocks)) + self._mesh_key() + self._model_key()
 
-    def _build(self):
-        return bnn_serve_fn(engine=self.engine, conv_impl=self.conv_impl,
-                            blocks=self.blocks, mesh=self.mesh)
+    def _build(self, ragged: bool = False):
+        return self.model.serve_fn(engine=self.engine,
+                                   conv_impl=self.conv_impl,
+                                   blocks=self.blocks, ragged=ragged,
+                                   mesh=self.mesh)
 
     def get(self, bucket: int):
         """The compiled callable for ``bucket``; builds (and counts a
@@ -240,7 +251,8 @@ class ExecutorCache:
             with TraceAnnotation("serve.assemble"):
                 pad = np.zeros((extent - n,) + images.shape[1:], images.dtype)
                 images = np.concatenate([np.asarray(images), pad], axis=0)
-        with TraceAnnotation("serve.h2d", shards=self.devices):
+        with TraceAnnotation("serve.h2d", shards=self.devices,
+                             bytes=images.nbytes):
             x = self._put(images)
         if self.mesh is not None:
             self.stats.on_sharded_put()
@@ -267,7 +279,8 @@ class ExecutorCache:
 
     def _ctor_kwargs(self) -> dict:
         return dict(engine=self.engine, conv_impl=self.conv_impl,
-                    blocks=self.blocks, mesh=self.mesh, stats=self.stats)
+                    blocks=self.blocks, mesh=self.mesh, stats=self.stats,
+                    model=self.model)
 
     def rebuild(self, *, packed=None, engine: Optional[str] = None,
                 mesh=_UNSET):
@@ -295,7 +308,8 @@ class ExecutorCache:
             new = self.key(b) not in self._fns
             built += new
             fn = self.get(b)
-            x = self._put(np.zeros((b,) + IMAGE_SHAPE, np.float32))
+            x = self._put(np.zeros((b,) + self.model.image_shape,
+                                   self.model.dtype))
             with _compile_span(new, b):
                 fn(self.packed, x).block_until_ready()
         return built
@@ -332,11 +346,11 @@ class RaggedExecutorCache(ExecutorCache):
 
     def key(self, extent: int) -> tuple:
         return (extent, self.engine, self.conv_impl,
-                blocks_key(self.blocks), "ragged") + self._mesh_key()
+                blocks_key(self.blocks), "ragged") + self._mesh_key() \
+            + self._model_key()
 
     def _build(self):
-        return bnn_serve_fn(engine=self.engine, conv_impl=self.conv_impl,
-                            blocks=self.blocks, ragged=True, mesh=self.mesh)
+        return super()._build(ragged=True)
 
     def extent_of(self, n: int) -> int:
         return extent_for(n, tile=self.tile, devices=self.devices)
@@ -355,5 +369,4 @@ __all__ = [
     "blocks_key",
     "default_extents",
     "extent_for",
-    "IMAGE_SHAPE",
 ]

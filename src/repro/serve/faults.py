@@ -283,12 +283,15 @@ class FallbackPolicy:
             return self.mega_params
         return self.fused_params
 
-    def next_engine(self, current: str) -> Optional[str]:
-        """The first ladder rung below ``current`` we hold params for,
-        or None when there is nowhere left to demote."""
-        from repro.core.bnn import SERVE_FALLBACKS
+    def next_engine(self, current: str,
+                    ladder: Optional[dict] = None) -> Optional[str]:
+        """The first rung below ``current`` on ``ladder`` (the served
+        model's; default the CIFAR BNN's `SERVE_FALLBACKS`) we hold
+        params for, or None when there is nowhere left to demote."""
+        if ladder is None:
+            from repro.core.bnn import SERVE_FALLBACKS as ladder
 
-        for rung in SERVE_FALLBACKS.get(current, ()):
+        for rung in ladder.get(current, ()):
             if self._has_params(rung):
                 return rung
         return None

@@ -24,15 +24,21 @@ import jax.numpy as jnp
 
 from repro.kernels import autotune
 from repro.kernels.autotune import AUTO, BlockConfig
+from repro.serve.model import CIFAR_BNN, ServedModel
 
 SERVE_KERNEL = "bnn_serve"
 
 Blocks = Union[str, BlockConfig]
 
 
-def serving_shape(engine: str, conv_impl: str, bucket: int) -> dict:
-    """The autotune-cache shape key for one serving configuration."""
-    return {"engine": engine, "conv": conv_impl, "bucket": bucket}
+def serving_shape(engine: str, conv_impl: str, bucket: int,
+                  model: ServedModel = CIFAR_BNN) -> dict:
+    """The autotune-cache shape key for one serving configuration (a
+    model other than the CIFAR BNN adds its name)."""
+    shape = {"engine": engine, "conv": conv_impl, "bucket": bucket}
+    if model != CIFAR_BNN:
+        shape["model"] = model.name
+    return shape
 
 
 def default_serving_candidates(bucket: int) -> list[Blocks]:
@@ -56,7 +62,8 @@ def default_serving_candidates(bucket: int) -> list[Blocks]:
 
 
 def load_serving_blocks(
-    engine: str, conv_impl: str, bucket: int
+    engine: str, conv_impl: str, bucket: int,
+    model: ServedModel = CIFAR_BNN,
 ) -> Blocks:
     """Cached serving config for this engine/conv_impl/bucket, or AUTO.
 
@@ -65,7 +72,7 @@ def load_serving_blocks(
     if not autotune.cache_enabled():
         return AUTO
     cfg = autotune.load_entry(
-        SERVE_KERNEL, serving_shape(engine, conv_impl, bucket)
+        SERVE_KERNEL, serving_shape(engine, conv_impl, bucket, model)
     )
     return cfg if cfg is not None else AUTO
 
@@ -80,6 +87,7 @@ def tune_serving_blocks(
     repeats: int = 1,
     cache: bool = True,
     timings: Optional[dict] = None,
+    model: ServedModel = CIFAR_BNN,
 ) -> Blocks:
     """Measure whole-forward wall time per candidate at ``bucket``;
     return (and optionally cache) the fastest config.
@@ -87,20 +95,20 @@ def tune_serving_blocks(
     Timing uses the shared :func:`kernels.autotune.time_call` protocol
     (one warmup/compile call, then the mean of ``repeats``). Pass a
     dict as ``timings`` to receive per-candidate seconds keyed by the
-    candidate (``"auto"`` or a ``BlockConfig``).
+    candidate (``"auto"`` or a ``BlockConfig``). ``model`` is the
+    served network (its serve fn and input shape and dtype; default the
+    CIFAR BNN).
     """
-    from repro.core.bnn import bnn_serve_fn  # local: avoid import cycle
-    from repro.serve.executor import IMAGE_SHAPE
-
     def operand():
-        return jnp.zeros((bucket,) + IMAGE_SHAPE, jnp.float32)
+        return jnp.zeros((bucket,) + model.image_shape, model.dtype)
 
     cands = list(candidates) if candidates is not None else (
         default_serving_candidates(bucket)
     )
     best, best_t = None, float("inf")
     for blocks in cands:
-        fn = bnn_serve_fn(engine=engine, conv_impl=conv_impl, blocks=blocks)
+        fn = model.serve_fn(engine=engine, conv_impl=conv_impl,
+                            blocks=blocks)
         t = autotune.time_call(lambda: fn(packed_params, operand()), repeats)
         if timings is not None:
             timings[blocks] = t
@@ -109,7 +117,8 @@ def tune_serving_blocks(
     assert best is not None, "empty candidate list"
     if cache and autotune.cache_enabled() and isinstance(best, BlockConfig):
         autotune.save_entry(
-            SERVE_KERNEL, serving_shape(engine, conv_impl, bucket), best,
+            SERVE_KERNEL, serving_shape(engine, conv_impl, bucket, model),
+            best,
             wall_s=best_t,
         )
     return best

@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.core import bireal
 from repro.core.bnn import (
     bnn_apply_fused,
     bnn_serve_fn,
@@ -74,17 +75,38 @@ def _params_for(engine, fused_params, mega_params):
     return mega_params if engine.startswith("megakernel") else fused_params
 
 
+# Bi-Real Net-18's block structure at a test's size (32x32 images, stage
+# widths 32/64/96/128), uint8 images; its batch divides 1 and 2 devices.
+BIREAL_ARCH = bireal.BiRealArch(image=32, stem_width=32,
+                                widths=(32, 64, 96, 128),
+                                depths=(2, 1, 1, 1), classes=10)
+
+
+@pytest.fixture(scope="module")
+def bireal_inputs():
+    params = jax.jit(bireal.init_bireal_params, static_argnums=1)(
+        jax.random.PRNGKey(1), BIREAL_ARCH)
+    rng = np.random.default_rng(7)
+    images = rng.integers(0, 256, size=(4, 32, 32, 3), dtype=np.uint8)
+    return (jax.jit(bireal.pack_bireal_params, static_argnums=1)(
+        params, BIREAL_ARCH), jnp.asarray(images))
+
+
 # Compiled serve fns shared across the parametrized matrix — one jit per
-# (engine, conv_impl, devices) cell, references included as devices=0.
+# (model, engine, conv_impl, devices) cell, references included as
+# devices=0.
 _FNS: dict = {}
+_SERVE_FNS = {"bnn": bnn_serve_fn,
+              "bireal": lambda **kw: bireal.bireal_serve_fn(
+                  arch=BIREAL_ARCH, **kw)}
 
 
-def _serve(engine, conv_impl, devices):
-    key = (engine, conv_impl, devices)
+def _serve(engine, conv_impl, devices, model="bnn"):
+    key = (model, engine, conv_impl, devices)
     if key not in _FNS:
         mesh = make_serving_mesh(devices) if devices else None
-        _FNS[key] = bnn_serve_fn(engine=engine, conv_impl=conv_impl,
-                                 mesh=mesh)
+        _FNS[key] = _SERVE_FNS[model](engine=engine, conv_impl=conv_impl,
+                                      mesh=mesh)
     return _FNS[key]
 
 
@@ -100,15 +122,30 @@ MATRIX = [
 ]
 
 
-@pytest.mark.parametrize("devices", [1, 2, 8])
-@pytest.mark.parametrize("engine,conv_impl", MATRIX)
-def test_sharded_logits_bit_identical(engine, conv_impl, devices,
-                                      fused_params, mega_params, images):
+# Bi-Real's engines, each on 1 and 2 devices, against its unsharded
+# ``xla`` oracle: the Pallas residual block conv (interpret mode here)
+# and the XLA engine are bit-identical, sharded or not.
+CASES = [pytest.param("bnn", e, c, d, id=f"{e}-{c}-{d}")
+         for e, c in MATRIX for d in (1, 2, 8)]
+CASES += [pytest.param("bireal", e, "direct", d, id=f"bireal-{e}-{d}")
+          for e in ("xnor", "xla") for d in (1, 2)]
+
+
+@pytest.mark.parametrize("model,engine,conv_impl,devices", CASES)
+def test_sharded_logits_bit_identical(model, engine, conv_impl, devices,
+                                      request):
     """THE acceptance matrix: sharded == single-device, bit for bit,
-    for every engine x conv_impl x device count."""
-    packed = _params_for(engine, fused_params, mega_params)
-    want = np.asarray(_serve(engine, conv_impl, 0)(packed, images))
-    got = np.asarray(_serve(engine, conv_impl, devices)(packed, images))
+    for every model x engine x conv_impl x device count."""
+    if model == "bireal":
+        packed, images = request.getfixturevalue("bireal_inputs")
+        want = np.asarray(_serve("xla", conv_impl, 0, model)(packed, images))
+    else:
+        packed = _params_for(engine, request.getfixturevalue("fused_params"),
+                             request.getfixturevalue("mega_params"))
+        images = request.getfixturevalue("images")
+        want = np.asarray(_serve(engine, conv_impl, 0)(packed, images))
+    got = np.asarray(_serve(engine, conv_impl, devices, model)(packed,
+                                                                images))
     np.testing.assert_array_equal(got, want)
 
 

@@ -177,3 +177,54 @@ def test_megakernel_forward_compiles(spec, monkeypatch):
     assert {"first_layer", "stage1", "fc_trunk", "final_bn"} <= scopes
     assert not any("conv_stage" in s or "tpu_custom_call" in s
                    for s in scopes)
+
+
+# Bi-Real Net-18's block convs: (map side, C, D, stride) of every
+# distinct block shape — each stage's identity block and the stride-2
+# first blocks of stages 2-4.
+BIREAL_BLOCKS = {
+    "stage1": (56, 64, 64, 1),
+    "stage2_stride2": (56, 64, 128, 2),
+    "stage2": (28, 128, 128, 1),
+    "stage3_stride2": (28, 128, 256, 2),
+    "stage3": (14, 256, 256, 1),
+    "stage4_stride2": (14, 256, 512, 2),
+    "stage4": (7, 512, 512, 1),
+}
+
+
+@pytest.mark.parametrize("block", sorted(BIREAL_BLOCKS))
+def test_bireal_block_conv_compiles(spec, block):
+    hw, c, d, stride = BIREAL_BLOCKS[block]
+    oh = (hw - 1) // stride + 1
+    hlo = _compile(
+        lambda w, x, a, b, cp, r: ops.bireal_block_conv(
+            w, x, 9 * c, a, b, cp, r, stride=stride, interpret=False),
+        spec((d, 9 * _words(c))), spec((BATCH, hw, hw, _words(c))),
+        spec((d,), F32), spec((d,), F32), spec((3, d, 3)),
+        spec((BATCH, oh, oh, d), F32),
+    )
+    _assert_kernel(hlo)
+
+
+def test_bireal_forward_compiles(spec, monkeypatch):
+    """The whole Bi-Real Net-18 serving forward at batch 8, uint8
+    224x224 images: 16 residual block convs, each one compiled kernel
+    named by its stage, and the float path in XLA."""
+    from repro.core import bireal
+
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    params = jax.eval_shape(bireal.init_bireal_params, jax.random.PRNGKey(0))
+    packed = jax.tree.map(lambda a: spec(a.shape, a.dtype),
+                          jax.eval_shape(bireal.pack_bireal_params, params))
+    fn = bireal.bireal_serve_fn(engine="xnor")
+    hlo = fn.lower(packed, spec((BATCH, 224, 224, 3), jnp.uint8)).compile(
+    ).as_text()
+    _assert_kernel(hlo, 16)
+    launches = re.findall(
+        r'%(\w+)\.\d+ = [^\n]*custom_call_target="tpu_custom_call"', hlo)
+    assert sorted(launches) == sorted(
+        f"bireal_block_conv{s}" for s in (1, 2, 3, 4) for _ in range(4))
+    scopes = set(re.findall(r'op_name="jit\([^)]*\)/([^/"]+)/', hlo))
+    assert not any("bireal_block_conv" in s or "tpu_custom_call" in s
+                   for s in scopes)
