@@ -301,8 +301,8 @@ def bireal_serve_fn(*, engine: str = "xnor", conv_impl: str = "direct",
     """The serving entry point: a jit-compiled ``(packed, images) ->
     logits`` over :func:`bireal_apply`, the counterpart of
     ``core.bnn.bnn_serve_fn``. The block convs are direct by
-    construction (``conv_impl`` is ignored) and one program per output
-    row, exact in the batch (``ragged`` is a no-op). ``mesh`` shards the
+    construction (``conv_impl`` is ignored) and exact in the batch
+    (``ragged`` is a no-op). ``mesh`` shards the
     batch over a 1-D serving mesh with the weights replicated
     (``distributed.sharding.shard_serving``)."""
     if engine not in BIREAL_ENGINES:

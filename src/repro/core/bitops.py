@@ -41,6 +41,7 @@ __all__ = [
     "direct_conv_oracle",
     "border_taps",
     "border_correction",
+    "border_map",
     "residual_conv_oracle",
     "maxpool2_packed",
     "megakernel_chain_xla",
@@ -329,7 +330,7 @@ def border_correction(w_pm1: jnp.ndarray, h: int, w: int, *, stride: int,
         -1, 3, 3).transpose(1, 0, 2)
 
 
-def _border_map(cpos: jnp.ndarray, oh: int, ow: int) -> jnp.ndarray:
+def border_map(cpos: jnp.ndarray, oh: int, ow: int) -> jnp.ndarray:
     """``c_pos [3, D, 3]`` spread over the output map: ``[OH, OW, D]``."""
     def classes(n):
         return jnp.asarray([0] + [1] * max(n - 2, 0) + [2] * (n > 1))
@@ -367,7 +368,7 @@ def residual_conv_oracle(
     """
     dot = direct_conv_dot(wp, xp, k_bits, kh=kh, kw=kw, stride=stride,
                           pad=pad)
-    dot = dot - _border_map(cpos, dot.shape[1], dot.shape[2])
+    dot = dot - border_map(cpos, dot.shape[1], dot.shape[2])
     y = (a.astype(jnp.float32) * dot.astype(jnp.float32)
          + b.astype(jnp.float32) + r.astype(jnp.float32))
     return y, pack_channels(y)

@@ -213,6 +213,51 @@ def heuristic_gemm_blocks(
     return BlockConfig(block_m=bm, block_n=bn, block_kw=bkw)
 
 
+@dataclasses.dataclass(frozen=True)
+class ResidualTile:
+    """The output positions one grid step of the residual block conv
+    covers (DESIGN.md §5.1): ``rows`` output rows of each of ``images``
+    whole images, ``ow_pad`` columns a row (OW up to a multiple of 8,
+    so the rows merge onto lanes cleanly); ``bands`` row bands tile an
+    image (``bands * rows >= OH``, the last band ragged when it is
+    larger)."""
+    images: int
+    rows: int
+    ow_pad: int
+    bands: int
+
+    @property
+    def lanes(self) -> int:
+        return self.images * self.rows * self.ow_pad
+
+
+# Int32 elements of one residual step's [block_d, positions] tile
+# (512 KiB). D counts at its 128-lane padding: the f32 shortcut and
+# output blocks put D on lanes, so a D = 64 stage gets no more
+# positions than a D = 128 one.
+RESIDUAL_TILE_ELEMS = 1 << 17
+
+
+def residual_conv_tile(n: int, oh: int, ow: int, d: int) -> ResidualTile:
+    """Positions a residual block-conv step covers, from the shapes
+    alone: ``n`` images of ``oh x ow`` outputs, D tile ``d``.
+
+    A step gets ``RESIDUAL_TILE_ELEMS / d`` lane positions (at least
+    128). An image that has more positions is cut into the fewest bands
+    of equal rows that fit; smaller images go several to a step, the
+    largest divisor of ``n`` that fits, so no step holds pad images.
+    """
+    ow_pad = _round_up(ow, 8)
+    lanes = max(128, RESIDUAL_TILE_ELEMS // _round_up(max(d, 1), 128))
+    per_image = oh * ow_pad
+    if per_image > lanes:
+        bands = -(-oh // max(1, lanes // ow_pad))
+        return ResidualTile(1, -(-oh // bands), ow_pad, bands)
+    fit = min(n, lanes // per_image)
+    images = max(i for i in range(1, fit + 1) if n % i == 0)
+    return ResidualTile(images, oh, ow_pad, 1)
+
+
 def heuristic_conv_block_d(d: int) -> BlockConfig:
     """Output-channel tile for the direct-conv kernels: all of D. The
     output tile ``[1, 1, OW, block_d/32]`` (fused) / ``[..., block_d]``
@@ -680,6 +725,9 @@ __all__ = [
     "gemm_blocks_legal",
     "heuristic_gemm_blocks",
     "heuristic_conv_block_d",
+    "ResidualTile",
+    "RESIDUAL_TILE_ELEMS",
+    "residual_conv_tile",
     "heuristic_megakernel_block_n",
     "megakernel_shape",
     "cache_enabled",

@@ -14,7 +14,7 @@ with the PR-1 fused epilogue (folded-BN affine -> sign -> repack along
 D). HBM sees: the packed map (read), the packed filters (read), the
 packed output (write). The patch matrix never exists.
 
-Three variants share the window gather:
+Three variants:
 
 * ``fused_direct_conv``    — full fused layer, packed words in AND out,
 * ``direct_conv_dot``      — epilogue-free int32 ±1 dot ``[N,OH,OW,D]``
@@ -23,6 +23,23 @@ Three variants share the window gather:
                              (DESIGN.md §5.1): the zero-padded dot via
                              the ``c_pos`` border correction, plus an f32
                              shortcut; f32 AND packed out.
+
+The first two compute one output row a grid step (``_gather_windows``).
+The residual conv runs at 224x224, where a row is 7-56 positions on 128
+lanes, so its step covers the positions
+``autotune.residual_conv_tile`` picks from the shapes
+(``_gather_band``): a band of rows of one image, or several whole
+images, at least 128 lanes at every Bi-Real stage. Its tile keeps D on
+sublanes and positions on lanes — the accumulator's ``[KW, N]`` layout,
+the repack along D a sublane sum, and a D = 64 stage still fills
+every lane. Each row is computed over OW rounded up to 8 columns
+(all-ones map words past the border), so (image, row, column) merge
+onto lanes with a clean relayout; the extra columns' outputs are
+dropped. ``c_pos`` comes in as a ``[bands, D, L]`` map of each lane's
+border class, fetched once when a step covers whole images. The TPU
+compiler asks for ~2.3-2.5 MiB of scoped VMEM at the cell's 64 rows,
+~6.3 MiB where the input map is 56x56 (its ``[58, 58, 2]`` block pads
+to 128 lanes, twice buffered; DESIGN.md §5.1).
 
 The popcount accumulation is BROADCAST-FREE (DESIGN.md §6): a
 ``lax.fori_loop`` over the kH*kW*CW packed filter words accumulates one
@@ -52,7 +69,9 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import bitops
 from repro.core.bitops import PACK_BITS
+from repro.kernels import autotune
 from repro.kernels.popcount import (
     DEFAULT_WORD_GROUP,
     accum_popcount_km,
@@ -162,34 +181,61 @@ def _direct_conv_call(wp, xpad, k_bits, a, b, *, kh, kw, stride, block_d,
     )(*operands)
 
 
-def _border_correction(c_ref, row, *, oh: int, ow: int):
-    """``c_pos`` of one output row, ``[bd, OW]`` int32.
+def _gather_band(x_ref, xs_ref, row0, *, kh: int, kw: int, stride: int,
+                 rows: int, ow_pad: int):
+    """Gather a step's windows — ``rows`` output rows from ``row0`` of
+    each image of the block — as word rows ``xs_ref [kH*kW*CW, L]``.
 
-    ``c_ref [3, bd, 3]`` holds the border classes (first / interior /
-    last output row x first / interior / last output column) of
-    :func:`repro.core.bitops.border_classes`; only the first and last
-    row and column of a 3x3, pad-1 conv touch the padding."""
-    cls = c_ref[jnp.where(row == 0, 0, jnp.where(row == oh - 1, 2, 1))]
-    col = lax.broadcasted_iota(jnp.int32, (cls.shape[0], ow), 1)
-    return jnp.where(col == 0, cls[:, 0:1],
-                     jnp.where(col == ow - 1, cls[:, 2:3], cls[:, 1:2]))
+    x_ref: [B, Hp, Wp, CW], wide and tall enough for every read. Row
+    ``(i*kW + j)*CW + cw`` holds tap (i, j), word cw; lane ``(b*rows +
+    r)*ow_pad + c`` is position (image b, row row0 + r, column c). One
+    strided load a tap; ``ow_pad % 8 == 0`` keeps the merge of (image,
+    row, column) onto lanes a clean relayout.
+    """
+    cw = x_ref.shape[-1]
+    for i in range(kh):
+        for j in range(kw):
+            tap = x_ref[:, pl.ds(row0 * stride + i, rows, stride=stride),
+                        pl.ds(j, ow_pad, stride=stride), :]  # [B, R, OWp, CW]
+            row = (i * kw + j) * cw
+            xs_ref[row:row + cw, :] = tap.reshape(-1, cw).T
 
 
 def _residual_conv_kernel(
-    x_ref, wt_ref, a_ref, b_ref, c_ref, r_ref, y_ref, p_ref, xs_ref, *,
-    kh: int, kw: int, stride: int, oh: int, ow: int, k_bits: int,
-    word_group: int,
+    x_ref, wt_ref, a_ref, b_ref, m_ref, r_ref, y_ref, p_ref, xs_ref,
+    *pad_ref, kh: int, kw: int, stride: int, rows: int, ow_pad: int,
+    k_bits: int, word_group: int,
 ):
-    row = pl.program_id(1)
-    _gather_windows(x_ref, xs_ref, row, kh=kh, kw=kw, stride=stride, ow=ow)
+    images, _, ow, bd = r_ref.shape
+    _gather_band(x_ref, xs_ref, pl.program_id(1) * rows, kh=kh, kw=kw,
+                 stride=stride, rows=rows, ow_pad=ow_pad)
     dot = _popcount_dot(wt_ref, xs_ref, k_bits, word_group=word_group,
                         accum="loop")
-    dot = dot - _border_correction(c_ref, row, oh=oh, ow=ow)  # zero-padded
+    dot = dot - m_ref[0]                                  # zero-padded
+    if pad_ref:
+        # Columns past OW hold stale values; their outputs are dropped.
+        pad_ref[0][:, :, :ow, :] = r_ref[...]
+        r = pad_ref[0][...]
+    else:
+        r = r_ref[...]
     # Same float op order as bitops.residual_conv_oracle.
     y = (a_ref[...] * dot.astype(jnp.float32) + b_ref[...]
-         + r_ref[0, 0].T)                                     # [bd, OW]
-    y_ref[...] = y.T[None, None]
-    p_ref[...] = sign_repack_m(y).T[None, None]
+         + r.reshape(-1, bd).T)                           # [bd, L]
+    shape = (images, rows, ow_pad)
+    y_ref[...] = y.T.reshape(shape + (bd,))[:, :, :ow]
+    p_ref[...] = sign_repack_m(y).T.reshape(
+        shape + (bd // PACK_BITS,))[:, :, :ow]
+
+
+def _border_map(cpos, oh: int, ow: int, tile) -> jnp.ndarray:
+    """``c_pos [3, D, 3]`` spread over each band's lanes: int32 ``[bands,
+    D, L]`` in :func:`_gather_band`'s lane order (rows and columns past
+    the map, whose outputs are dropped, read 0)."""
+    m = bitops.border_map(cpos, oh, ow)                      # [OH, OW, D]
+    m = jnp.pad(m, ((0, tile.bands * tile.rows - oh),
+                    (0, tile.ow_pad - ow), (0, 0)))
+    m = m.reshape(tile.bands, -1, m.shape[-1]).transpose(0, 2, 1)
+    return jnp.tile(m, (1, 1, tile.images))
 
 
 @functools.partial(
@@ -225,7 +271,10 @@ def residual_direct_conv(
     (border classes) turns it into the exact zero-padded dot. ``a``/
     ``b`` ``[D_pad, 1]`` f32, ``r`` the f32 shortcut ``[N, OH, OW,
     D_pad]``; rows past the true D padded ``a=0, b=+1, c_pos=0, r=0``.
-    ``name`` names the launch in the compiled program and a trace.
+    A grid step covers the positions :func:`autotune.residual_conv_tile`
+    picks from the shapes: a band of rows of one image, or whole
+    images. ``name`` names the launch in the compiled program and a
+    trace.
     """
     n, hp, wp_sp, cw = xpad.shape
     d_pad, kwords = wp.shape
@@ -236,41 +285,62 @@ def residual_direct_conv(
     assert a.shape == b.shape == (d_pad, 1), (a.shape, b.shape)
     assert cpos.shape == (3, d_pad, 3), cpos.shape
     assert r.shape == (n, oh, ow, d_pad), (r.shape, (n, oh, ow, d_pad))
+    tile = autotune.residual_conv_tile(n, oh, ow, block_d)
+    bi, rows, ow_pad = tile.images, tile.rows, tile.ow_pad
+    oh_pad = tile.bands * rows
+    # Grow the map with all-ones words until every row of the last band
+    # and every padded column reads inside it; their outputs are dropped.
+    grow_h = max(0, (oh_pad - 1) * stride + kh - hp)
+    grow_w = max(0, (ow_pad - 1) * stride + kw - wp_sp)
+    if grow_h or grow_w:
+        xpad = jnp.pad(xpad, ((0, 0), (0, grow_h), (0, grow_w), (0, 0)),
+                       constant_values=-1)
+    if oh_pad > oh:
+        r = jnp.pad(r, ((0, 0), (0, oh_pad - oh), (0, 0), (0, 0)))
     kernel = functools.partial(
-        _residual_conv_kernel, kh=kh, kw=kw, stride=stride, oh=oh, ow=ow,
-        k_bits=k_bits, word_group=word_group,
+        _residual_conv_kernel, kh=kh, kw=kw, stride=stride, rows=rows,
+        ow_pad=ow_pad, k_bits=k_bits, word_group=word_group,
     )
-    per_d = pl.BlockSpec((block_d, 1), lambda ni, oi, di: (di, 0))
-    return pl.pallas_call(
+    per_d = pl.BlockSpec((block_d, 1), lambda g, t, di: (di, 0))
+
+    def band(words):
+        return pl.BlockSpec((bi, rows, ow, words),
+                            lambda g, t, di: (g, t, 0, di))
+
+    scratch = [pltpu.VMEM((kwords, tile.lanes), jnp.int32)]
+    if ow_pad > ow:
+        scratch.append(pltpu.VMEM((bi, rows, ow_pad, block_d), jnp.float32))
+    y, packed = pl.pallas_call(
         kernel,
-        grid=(n, oh, d_pad // block_d),
+        grid=(n // bi, tile.bands, d_pad // block_d),
         in_specs=[
-            pl.BlockSpec((1, hp, wp_sp, cw), lambda ni, oi, di: (ni, 0, 0, 0)),
-            pl.BlockSpec((kwords, block_d), lambda ni, oi, di: (0, di)),
+            pl.BlockSpec((bi,) + xpad.shape[1:],
+                         lambda g, t, di: (g, 0, 0, 0)),
+            pl.BlockSpec((kwords, block_d), lambda g, t, di: (0, di)),
             per_d,
             per_d,
-            pl.BlockSpec((3, block_d, 3), lambda ni, oi, di: (0, di, 0)),
-            pl.BlockSpec((1, 1, ow, block_d),
-                         lambda ni, oi, di: (ni, oi, 0, di)),
+            pl.BlockSpec((1, block_d, tile.lanes),
+                         lambda g, t, di: (t, di, 0)),
+            band(block_d),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, ow, block_d),
-                         lambda ni, oi, di: (ni, oi, 0, di)),
-            pl.BlockSpec((1, 1, ow, block_d // PACK_BITS),
-                         lambda ni, oi, di: (ni, oi, 0, di)),
-        ],
+        out_specs=[band(block_d), band(block_d // PACK_BITS)],
         out_shape=[
-            jax.ShapeDtypeStruct((n, oh, ow, d_pad), jnp.float32),
-            jax.ShapeDtypeStruct((n, oh, ow, d_pad // PACK_BITS), jnp.int32),
+            jax.ShapeDtypeStruct((n, oh_pad, ow, d_pad), jnp.float32),
+            jax.ShapeDtypeStruct((n, oh_pad, ow, d_pad // PACK_BITS),
+                                 jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM((kwords, ow), jnp.int32)],
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
         interpret=interpret,
         name=name,
     )(xpad, wp.T, a.astype(jnp.float32), b.astype(jnp.float32),
-      cpos.astype(jnp.int32), r.astype(jnp.float32))
+      _border_map(cpos.astype(jnp.int32), oh, ow, tile),
+      r.astype(jnp.float32))
+    if oh_pad > oh:
+        y, packed = y[:, :oh], packed[:, :oh]
+    return y, packed
 
 
 @functools.partial(

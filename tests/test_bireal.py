@@ -27,7 +27,7 @@ import pytest
 from jax import lax
 
 from repro.core import bireal, bitops
-from repro.kernels import ops
+from repro.kernels import autotune, ops
 from repro.models import bireal_reference
 from repro.serve import (ContinuousServingEngine, FallbackPolicy, FaultPlan,
                          FaultSpec)
@@ -56,11 +56,11 @@ def images():
     return rng.integers(0, 256, size=(4, 32, 32, 3), dtype=np.uint8)
 
 
-def _layer(hw, c, d, stride, seed=0):
+def _layer(hw, c, d, stride, seed=0, n=2):
     """A block conv's operands: packed map, packed filters, +-1 filters,
     affine, c_pos and shortcut."""
     rng = np.random.default_rng(seed)
-    x = rng.normal(size=(2, hw, hw, c)).astype(np.float32)
+    x = rng.normal(size=(n, hw, hw, c)).astype(np.float32)
     w = np.where(rng.normal(size=(d, 3, 3, c)) >= 0, 1.0, -1.0)
     w = jnp.asarray(w, jnp.float32)
     oh = (hw - 1) // stride + 1
@@ -70,16 +70,38 @@ def _layer(hw, c, d, stride, seed=0):
         a=jnp.asarray(rng.uniform(0.01, 0.1, d), jnp.float32),
         b=jnp.asarray(rng.normal(size=d), jnp.float32),
         cpos=bitops.border_correction(w, hw, hw, stride=stride, pad=1),
-        r=jnp.asarray(rng.normal(size=(2, oh, oh, d)), jnp.float32))
+        r=jnp.asarray(rng.normal(size=(n, oh, oh, d)), jnp.float32))
 
 
-@pytest.mark.parametrize("hw,c,d,stride,block_d", [
-    (7, 64, 64, 1, "auto"),
-    (8, 32, 64, 2, "auto"),
-    (4, 64, 96, 2, 64),       # D padded to two 64-channel tiles
-], ids=["7x7_stride1", "8x8_stride2", "ragged_d_stride2"])
-def test_residual_kernel_matches_oracle(hw, c, d, stride, block_d):
-    L = _layer(hw, c, d, stride)
+# (map side, C, D, stride, N, block_d) and the step it must take:
+# (images, rows, bands) of autotune.residual_conv_tile.
+RESIDUAL_CASES = {
+    "7x7_stride1": ((7, 64, 64, 1, 2, "auto"), (2, 7, 1)),
+    "8x8_stride2": ((8, 32, 64, 2, 2, "auto"), (2, 4, 1)),
+    "ragged_d_stride2": ((4, 64, 96, 2, 2, 64), (2, 2, 1)),
+    # OW 7 and 14 (columns padded to 8 and 16), several images a step
+    "ow7_images4": ((7, 32, 512, 1, 4, "auto"), (4, 7, 1)),
+    "ow14_stride1": ((14, 32, 256, 1, 2, "auto"), (2, 14, 1)),
+    "ow14_stride2_even_map": ((28, 32, 256, 2, 2, "auto"), (2, 14, 1)),
+    # bands of rows: dividing OH, and a ragged last band
+    "bands_stride1": ((20, 32, 512, 1, 1, "auto"), (1, 10, 2)),
+    "bands_ragged_stride2": ((46, 32, 512, 2, 1, "auto"), (1, 8, 3)),
+    # N not a multiple of the images a step would take
+    "n1": ((7, 32, 512, 1, 1, "auto"), (1, 7, 1)),
+    "n3": ((14, 32, 256, 1, 3, "auto"), (1, 14, 1)),
+    # D padded to a word: 40 channels in a 64-row tile
+    "d40_padded": ((7, 32, 40, 1, 3, "auto"), (3, 7, 1)),
+}
+
+
+@pytest.mark.parametrize("case", list(RESIDUAL_CASES))
+def test_residual_kernel_matches_oracle(case):
+    (hw, c, d, stride, n, block_d), step = RESIDUAL_CASES[case]
+    L = _layer(hw, c, d, stride, n=n)
+    oh = (hw - 1) // stride + 1
+    bd = block_d if block_d != "auto" else -(-d // 32) * 32
+    tile = autotune.residual_conv_tile(n, oh, oh, bd)
+    assert (tile.images, tile.rows, tile.bands) == step
     args = (L["wp"], L["xp"], 9 * c, L["a"], L["b"], L["cpos"], L["r"])
     y0, p0 = bitops.residual_conv_oracle(*args, kh=3, kw=3, stride=stride,
                                          pad=1)
@@ -88,6 +110,31 @@ def test_residual_kernel_matches_oracle(hw, c, d, stride, block_d):
     np.testing.assert_array_equal(np.asarray(y1), np.asarray(y0))
     np.testing.assert_array_equal(np.asarray(p1), np.asarray(p0))
     assert p1.shape[-1] == -(-d // 32)
+
+
+def test_residual_tile_per_bireal18_geometry():
+    """A step fills the lanes at every block of Bi-Real Net-18 at the
+    cell's 64-row extent: bands of rows at stages 1-2, whole images at
+    stages 3-4 (DESIGN.md §5.1)."""
+    got = {}
+    for blk in bireal.BIREAL18.blocks():
+        t = autotune.residual_conv_tile(64, blk.hw_out, blk.hw_out,
+                                        blk.c_out)
+        got[(blk.hw, blk.c_in, blk.c_out, blk.stride)] = (
+            t.images, t.rows, t.ow_pad, t.bands, t.lanes)
+    assert got == {
+        (56, 64, 64, 1): (1, 14, 56, 4, 784),
+        (56, 64, 128, 2): (1, 28, 32, 1, 896),
+        (28, 128, 128, 1): (1, 28, 32, 1, 896),
+        (28, 128, 256, 2): (2, 14, 16, 1, 448),
+        (14, 256, 256, 1): (2, 14, 16, 1, 448),
+        (14, 256, 512, 2): (4, 7, 8, 1, 224),
+        (7, 512, 512, 1): (4, 7, 8, 1, 224),
+    }
+    assert all(lanes >= 128 for *_, lanes in got.values())
+    # Every extent class of the cell's engine divides into whole steps.
+    for n in (1, 2, 4, 8, 16, 24, 32, 40, 48, 56):
+        assert autotune.residual_conv_tile(n, 7, 7, 512).images == min(n, 4)
 
 
 @pytest.mark.parametrize("hw,stride", [(7, 1), (8, 2), (2, 1), (2, 2),
@@ -102,7 +149,7 @@ def test_border_correction_gives_the_zero_padded_dot(hw, stride):
     one_padded = bitops.direct_conv_dot(L["wp"], L["xp"], 9 * 32, kh=3,
                                         kw=3, stride=stride, pad=1)
     oh = one_padded.shape[1]
-    got = one_padded - bitops._border_map(L["cpos"], oh, oh)
+    got = one_padded - bitops.border_map(L["cpos"], oh, oh)
     np.testing.assert_array_equal(np.asarray(got),
                                   np.asarray(want).astype(np.int32))
     if hw == 7:   # 24 of the 49 positions are border positions

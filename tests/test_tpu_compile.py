@@ -193,16 +193,23 @@ BIREAL_BLOCKS = {
 }
 
 
-@pytest.mark.parametrize("block", sorted(BIREAL_BLOCKS))
-def test_bireal_block_conv_compiles(spec, block):
+# The served extent of the Bi-Real cell: a step of the block conv covers
+# several whole images there (DESIGN.md §5.1), so VMEM is fullest.
+BIREAL_BATCH = 64
+
+
+@pytest.mark.parametrize("block,batch", [
+    pytest.param(b, n, id=b if n == BATCH else f"{b}-{n}")
+    for n in (BATCH, BIREAL_BATCH) for b in sorted(BIREAL_BLOCKS)])
+def test_bireal_block_conv_compiles(spec, block, batch):
     hw, c, d, stride = BIREAL_BLOCKS[block]
     oh = (hw - 1) // stride + 1
     hlo = _compile(
         lambda w, x, a, b, cp, r: ops.bireal_block_conv(
             w, x, 9 * c, a, b, cp, r, stride=stride, interpret=False),
-        spec((d, 9 * _words(c))), spec((BATCH, hw, hw, _words(c))),
+        spec((d, 9 * _words(c))), spec((batch, hw, hw, _words(c))),
         spec((d,), F32), spec((d,), F32), spec((3, d, 3)),
-        spec((BATCH, oh, oh, d), F32),
+        spec((batch, oh, oh, d), F32),
     )
     _assert_kernel(hlo)
 
